@@ -67,8 +67,8 @@ def _roofline(lowered_fn, args_, kind, arch, mesh, model_flops, hardware):
     try:
         import jax
         from repro.core.hardware import get_profile
+        from repro.distributed.sharding import mesh_axis_label
         from repro.launch.hlo_stats import analyze_hlo
-        from repro.launch.mesh import mesh_axis_label
         from repro.launch.roofline import roofline_row
         chips = int(mesh.size) if mesh is not None else 1
         hlo = jax.jit(lowered_fn).lower(*args_).compile().as_text()
@@ -152,14 +152,15 @@ def cmd_serve(args) -> None:
     import jax
     from repro.configs.catalog import get_config
     from repro.core.hardware import resolve_hardware
-    from repro.launch.mesh import build_mesh, mesh_axis_label
+    from repro.distributed.sharding import mesh_axis_label
+    from repro.launch.mesh import build_mesh
     from repro.models import build_model
     from repro.models.model import active_param_count
     from repro.profiling import build_profile, trace
     from repro.serve import Engine, ServeConfig
 
     hardware = resolve_hardware(args.hardware)
-    mesh = build_mesh(args.mesh, hardware=hardware) if args.mesh else None
+    mesh = build_mesh(args.mesh) if args.mesh else None
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
@@ -178,10 +179,13 @@ def cmd_serve(args) -> None:
         eng.generate(prompts, args.max_new)
 
     st = eng.stats()
+    # the continuous scheduler keeps KV in pages, never in a dense pool
+    cache = (eng._cache if eng._cache is not None
+             else model.init_cache(args.batch, args.max_len))
     roof = _roofline(
         eng._with_mesh(model.decode_step),
         (eng.params, jax.numpy.zeros((args.batch, 1), jax.numpy.int32),
-         eng._cache, jax.numpy.int32(0),
+         cache, jax.numpy.int32(0),
          jax.numpy.zeros((args.batch,), jax.numpy.int32)),
         "decode", cfg.name, mesh,
         2 * active_param_count(model) * args.batch, hardware)
@@ -206,7 +210,8 @@ def cmd_train(args) -> None:
     from repro.core.hardware import resolve_hardware
     from repro.data import DataConfig, TokenPipeline
     from repro.distributed import sharding as sh
-    from repro.launch.mesh import build_mesh, mesh_axis_label
+    from repro.distributed.sharding import mesh_axis_label
+    from repro.launch.mesh import build_mesh
     from repro.models import build_model
     from repro.models.model import active_param_count
     from repro.optim import AdamW
@@ -214,7 +219,7 @@ def cmd_train(args) -> None:
     from repro.train import Trainer, TrainerConfig, init_train_state
 
     hardware = resolve_hardware(args.hardware)
-    mesh = build_mesh(args.mesh, hardware=hardware) if args.mesh else None
+    mesh = build_mesh(args.mesh) if args.mesh else None
     rules = sh.rules_for_mesh(mesh) if mesh is not None else None
     cfg = get_config(args.arch)
     if not args.full:
@@ -302,7 +307,9 @@ def main() -> None:
     pd.add_argument("b")
 
     args = ap.parse_args()
-    if args.cmd in ("serve", "train"):
+    if args.cmd in ("serve", "train") and args.mesh:
+        from repro.launch.common import apply_latency_hiding_flags
+        print(f"[flags] {apply_latency_hiding_flags(args.hardware)}")
         n = _mesh_devices(args.mesh)
         if n and n > 1:
             _ensure_devices(n)

@@ -38,8 +38,8 @@ SERVE_KW = dict(max_batch=4, max_len=64)
 
 def mesh_label(mesh_spec: Optional[str]) -> str:
     """Mesh coordinate of a case id: ``"single"`` or ``"data4xmodel2"``
-    (same label :func:`repro.launch.mesh.mesh_axis_label` derives from the
-    built mesh, computed here without touching jax devices)."""
+    (same label :func:`repro.distributed.sharding.mesh_axis_label` derives
+    from the built mesh, computed here without touching jax devices)."""
     if not mesh_spec:
         return "single"
     from repro.launch.mesh import parse_mesh_spec

@@ -15,6 +15,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.gemm_api import _ctx
 from repro.core.registry import GLOBAL_REGISTRY, LookupResult, OP_FLASH_ATTENTION
@@ -52,8 +53,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ``op="flash_attention"`` entry for ``(S, S_kv, d)`` on the ambient
         context's hardware — exact tuned shape first, then nearest-shape,
         generic, and per-hardware default tiers.
-      interpret: force/disable Pallas interpret mode; default: interpret
-        everywhere except on real TPU backends.
+      interpret: force/disable Pallas interpret mode.  By default the
+        kernel is compiled by Mosaic under the ``pallas-tpu`` backend and
+        on a TPU platform (unless the context asks for
+        ``pallas-interpret``), and interpreted elsewhere.
 
     Returns:
       Attention output, shape ``(B, S, H, d)``, in ``q.dtype``.
@@ -65,16 +68,56 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         with execution_context(hardware=TPU_V5E.name):
             out = flash_attention(q, k, v, causal=True)   # tuned (bq, bk)
     """
+    from repro.distributed.ctx import get_policy
     from repro.kernels import flash_attention as fa_kernel
+    from repro.kernels import ops
     b, sq, h, d = q.shape
     skv = k.shape[1]
+    ctx = _ctx()
     if bq is None or bk is None:
-        ctx = _ctx()
         cfg = flash_tile_lookup(ctx.resolve_hardware(), q.dtype,
                                 sq, skv, d).config
         bq = bq if bq is not None else cfg.bq
         bk = bk if bk is not None else cfg.bk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return fa_kernel.flash_attention(q, k, v, causal=causal, bq=bq, bk=bk,
-                                     interpret=interpret, kv_start=kv_start)
+        backend = ctx.resolve_backend()
+        interpret = (backend == ops.BACKEND_PALLAS_INTERPRET
+                     or (backend != ops.BACKEND_PALLAS_TPU
+                         and jax.default_backend() != "tpu"))
+
+    def kernel(q, k, v, kv_start=None):
+        return fa_kernel.flash_attention(q, k, v, causal=causal, bq=bq,
+                                         bk=bk, interpret=interpret,
+                                         kv_start=kv_start)
+
+    policy = get_policy()
+    if policy is None:
+        return kernel(q, k, v, kv_start)
+    return _per_shard(policy, kernel, q, k, v, kv_start)
+
+
+def _per_shard(policy, kernel, q, k, v, kv_start):
+    """Run ``kernel`` under ``shard_map`` on each shard's local operands:
+    Mosaic kernels cannot be partitioned by the compiler.  Rows split over
+    the batch axes and heads over the tensor axis; grouped KV heads are
+    expanded first when the tensor axis does not divide them."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed import sharding as sh
+    mesh, rules = policy.mesh, policy.rules
+    b, _, h, _ = q.shape
+    kvh = k.shape[2]
+    b_ax = rules.batch_axes
+    if b % sh.axis_size(mesh, b_ax):
+        b_ax = None
+    h_ax = rules.tensor_axis
+    if h_ax and h % sh.axis_size(mesh, h_ax):
+        h_ax = None
+    if h_ax and kvh % sh.axis_size(mesh, h_ax):
+        k = jnp.repeat(k, h // kvh, axis=2)
+        v = jnp.repeat(v, h // kvh, axis=2)
+    spec = P(b_ax, None, h_ax, None)
+    args, specs = (q, k, v), (spec, spec, spec)
+    if kv_start is not None:
+        args, specs = args + (kv_start,), specs + (P(b_ax),)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=spec,
+                         check_vma=False)(*args)

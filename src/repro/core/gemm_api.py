@@ -37,7 +37,9 @@ class ExecutionContext:
     # the profile layer: $REPRO_HARDWARE, else jax.devices() detection —
     # an explicit execution_context(hardware=...) override always wins.
     hardware: Optional[str] = None
-    capture: Optional[List[Tuple[int, int, int]]] = None  # GEMM shape trace
+    capture: Optional[list] = None      # GEMM shape trace
+    # When True, each captured entry is a (global, per-shard) shape pair.
+    capture_per_shard: bool = False
     # When True, 16-bit matmuls emit 16-bit outputs at the tile level, so
     # cross-shard partial-sum all-reduces run in bf16 instead of f32 (halves
     # the dominant TP collective; MXU still accumulates f32 within a shard).
@@ -84,10 +86,15 @@ def current_hardware() -> str:
 
 
 @contextlib.contextmanager
-def capture_gemm_shapes():
-    """Collect every (m, k, n) issued under this scope — feeds the tuner."""
-    shapes: List[Tuple[int, int, int]] = []
-    with execution_context(capture=shapes):
+def capture_gemm_shapes(per_shard: bool = False):
+    """Collect every (m, k, n) issued under this scope — feeds the tuner.
+
+    With ``per_shard`` each entry is a ``((m, k, n), (lm, lk, ln))`` pair:
+    the shape ``matmul`` was called with and the shape each shard's kernel
+    runs under the ambient mesh policy (the same shape without one).
+    """
+    shapes: List = []
+    with execution_context(capture=shapes, capture_per_shard=per_shard):
         yield shapes
 
 
@@ -120,7 +127,8 @@ _dot_bf16_reduce.defvjp(_dot_bf16_reduce_fwd, _dot_bf16_reduce_bwd)
 
 
 def matmul(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
-           activation: Optional[str] = None, out_dtype=None) -> jax.Array:
+           activation: Optional[str] = None, out_dtype=None,
+           w_axes: Optional[Tuple[Optional[str], str]] = None) -> jax.Array:
     """``x @ w`` — the only matmul primitive the model zoo uses.
 
     Leading dims of ``x`` are flattened into the GEMM's M dimension; the
@@ -138,6 +146,10 @@ def matmul(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
       activation: optional fused activation: ``"relu" | "gelu" | "silu" |
         "tanh"``.
       out_dtype: output dtype (default: the operands' result type).
+      w_axes: logical axes of ``w`` (as in its ``ParamSpec``); required
+        under a mesh, where they say which weight dim the sharding rules
+        split for the per-shard kernel.  ``(None, None)`` is a replicated
+        weight.
 
     Returns:
       ``x @ w`` with shape ``(..., N)``, accumulated in float32.
@@ -162,11 +174,22 @@ def matmul(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
         m *= d
     x2 = x.reshape(m, k)
 
+    from repro.distributed.ctx import get_policy
+    policy = get_policy()
+    layout = (_shard_layout(policy, m, k, n, w_axes)
+              if policy is not None else None)
     if ctx.capture is not None:
-        ctx.capture.append((m, k, n))
+        if ctx.capture_per_shard:
+            ctx.capture.append(((m, k, n), layout[1] if layout else (m, k, n)))
+        else:
+            ctx.capture.append((m, k, n))
 
     config = None
     if backend in (ops.BACKEND_PALLAS_TPU, ops.BACKEND_PALLAS_INTERPRET):
+        if layout is not None:
+            out = _per_shard_gemm(policy, layout, ctx.resolve_hardware(),
+                                  backend, x2, w, bias, activation, out_dtype)
+            return out.reshape(*lead, n)
         # First lookup lazily pulls committed tuned/<hardware>.json DBs into
         # the global registry, so a fresh process serves tuned tiles with no
         # explicit setup; untuned shapes resolve via nearest-shape fallback.
@@ -185,6 +208,68 @@ def matmul(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
                    activation=activation, out_dtype=out_dtype,
                    bf16_partials=ctx.bf16_partials)
     return out.reshape(*lead, n)
+
+
+def _shard_layout(policy, m: int, k: int, n: int, w_axes):
+    """Where a GEMM splits on the policy's mesh: the mesh axes of its M, K
+    and N dims, and the per-shard ``(m, k, n)`` each shard's kernel runs.
+
+    M splits over the batch axes, and the weight keeps the tensor-parallel
+    split its sharding rules give it (FSDP shards are gathered first).
+    """
+    from repro.distributed import sharding as sh
+    if w_axes is None:
+        raise ValueError(
+            f"matmul({m}x{k} @ {k}x{n}) under a mesh needs w_axes, the "
+            "weight's logical axes as in its ParamSpec ((None, None) for a "
+            "replicated weight); without them a sharded weight would be "
+            "gathered whole into every shard")
+    mesh, rules = policy.mesh, policy.rules
+    k_ax, n_ax = sh.weight_compute_axes(mesh, rules, (k, n), w_axes)
+    m_ax = rules.batch_axes
+    if m % sh.axis_size(mesh, m_ax):
+        m_ax = None
+    local = (m // sh.axis_size(mesh, m_ax), k // sh.axis_size(mesh, k_ax),
+             n // sh.axis_size(mesh, n_ax))
+    return (m_ax, k_ax, n_ax), local
+
+
+def _per_shard_gemm(policy, layout, hardware: str, backend: str, x2, w,
+                    bias, activation, out_dtype) -> jax.Array:
+    """The Pallas GEMM on a mesh: Mosaic kernels cannot be partitioned by
+    the compiler, so the kernel runs under ``shard_map`` on each shard's
+    local operands (split as :func:`_shard_layout` says), with its tiles
+    looked up by the *local* shape.
+
+    A weight split along K (row parallel) leaves f32 partial sums that are
+    all-reduced before the bias/activation epilogue runs once on the full
+    sum.
+    """
+    from jax.sharding import PartitionSpec as P
+    from repro.core.registry import OP_GEMM
+    from repro.distributed.sharding import mesh_axis_label
+    from repro.kernels.ref import apply_epilogue
+    mesh = policy.mesh
+    (m_ax, k_ax, n_ax), local = layout
+    config = GLOBAL_REGISTRY.lookup_op(OP_GEMM, hardware, x2.dtype, local,
+                                       mesh=mesh_axis_label(mesh)).config
+    out_dtype = out_dtype or jnp.result_type(x2.dtype, w.dtype)
+
+    def shard_fn(xl, wl, *bl):
+        bl = bl[0] if bl else None
+        if k_ax is None:
+            return ops.gemm(xl, wl, config=config, backend=backend, bias=bl,
+                            activation=activation, out_dtype=out_dtype)
+        part = ops.gemm(xl, wl, config=config, backend=backend,
+                        out_dtype=jnp.float32)
+        return apply_epilogue(jax.lax.psum(part, k_ax), bias=bl,
+                              activation=activation).astype(out_dtype)
+
+    args, specs = (x2, w), (P(m_ax, k_ax), P(k_ax, n_ax))
+    if bias is not None:
+        args, specs = args + (bias,), specs + (P(n_ax),)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=specs,
+                         out_specs=P(m_ax, n_ax), check_vma=False)(*args)
 
 
 def einsum(subscripts: str, *operands, **kw):

@@ -76,11 +76,11 @@ class HardwareProfile:
     default_backend: str = "pallas-tpu"   # kernels.ops backend string
     gemm_block: Tuple[int, int, int] = (128, 128, 128)   # seeded default tier
     flash_block: Tuple[int, int] = (128, 128)
-    #: XLA flags enabling async collectives / latency-hiding scheduling on
-    #: this backend.  Applied by ``launch.mesh.apply_latency_hiding_flags``
-    #: *before* backend init (XLA reads XLA_FLAGS once), so collectives the
-    #: decode loop issues can overlap with compute instead of serializing it.
-    #: Empty for backends whose runtime has no such scheduler (interpret CPU).
+    #: Compiler flags that let collectives overlap compute on this backend
+    #: (async collectives, latency-hiding scheduling).  Set by
+    #: ``launch.common.apply_latency_hiding_flags`` before the backend
+    #: starts, for mesh runs.  Empty where the runtime has no such scheduler
+    #: (interpret CPU).
     xla_latency_flags: Tuple[str, ...] = ()
 
     def __post_init__(self):
@@ -206,38 +206,44 @@ get_hardware = get_profile
 
 
 # ---------------------------------------------------------------------------
-# Detection: env pin > jax.devices() platform
+# Detection: env pin > jax.devices()
 # ---------------------------------------------------------------------------
 
-#: jax platform string -> registered profile name
-PLATFORM_DEFAULT_PROFILE: Dict[str, str] = {
-    "cpu": CPU_INTERPRET.name,
-    "gpu": GPU_GENERIC.name,
-    "cuda": GPU_GENERIC.name,
-    "rocm": GPU_GENERIC.name,
-    "tpu": TPU_V5E.name,
-}
+#: TPU ``device_kind`` (as JAX reports it) -> registered profile name.  A TPU
+#: whose kind is not listed is an error: its peaks and tiles are unknown.
+TPU_DEVICE_KINDS: Dict[str, str] = {"TPU v5 lite": TPU_V5E.name}
+
+#: jax platform strings of the GPU backends
+GPU_PLATFORMS = ("gpu", "cuda", "rocm")
 
 
 def detect_hardware(devices: Optional[Iterable] = None) -> str:
     """Profile name for this process: ``$REPRO_HARDWARE`` if set, else the
-    default profile for ``jax.devices()``'s platform (CPU-only hosts resolve
-    to ``cpu-interpret``).  ``devices`` is injectable for tests."""
+    profile of ``jax.devices()`` (``devices`` is injectable for tests).
+
+    An accelerator wins over the host CPU.  A TPU is keyed on its
+    ``device_kind``; an unknown kind raises instead of borrowing another
+    chip's peaks and tiles.
+    """
     env = os.environ.get(HARDWARE_ENV)
     if env:
         return canonical_name(env)
-    if devices is not None:
-        platforms = {getattr(d, "platform", "cpu") for d in devices}
-        for plat in ("tpu", "gpu", "cuda", "rocm"):   # accelerator wins
-            if plat in platforms:
-                return PLATFORM_DEFAULT_PROFILE[plat]
-        return CPU_INTERPRET.name
-    try:
+    if devices is None:
         import jax
-        platform = jax.default_backend()
-    except Exception:   # pragma: no cover - jax always importable here
-        return CPU_INTERPRET.name
-    return PLATFORM_DEFAULT_PROFILE.get(platform, CPU_INTERPRET.name)
+        devices = jax.devices()
+    devices = list(devices)
+    tpus = [d for d in devices if d.platform == "tpu"]
+    if tpus:
+        kind = tpus[0].device_kind
+        if kind not in TPU_DEVICE_KINDS:
+            raise RuntimeError(
+                f"unknown TPU device_kind {kind!r}; known: "
+                f"{sorted(TPU_DEVICE_KINDS)} (register a profile for it, or "
+                f"pin one with ${HARDWARE_ENV})")
+        return TPU_DEVICE_KINDS[kind]
+    if any(d.platform in GPU_PLATFORMS for d in devices):
+        return GPU_GENERIC.name
+    return CPU_INTERPRET.name
 
 
 def resolve_hardware(name: Optional[str] = None) -> str:

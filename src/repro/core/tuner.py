@@ -128,7 +128,7 @@ def sweep_gemm(
     search: str = SEARCH_GUIDED,
     top_k: int = DEFAULT_TOP_K,
     prune_factor: float = DEFAULT_PRUNE_FACTOR,
-    backend: str = ops.BACKEND_PALLAS_INTERPRET,
+    backend: Optional[str] = None,
     repeats: int = 3,
     registry: Optional[TileRegistry] = None,
     record: bool = True,
@@ -137,6 +137,8 @@ def sweep_gemm(
 
     ``hardware`` accepts a :class:`HardwareProfile`, a registered profile
     name (``"cpu-interpret"``, ...), or ``None`` to auto-detect the host.
+    Measure mode times the kernel on ``backend``, by default the profile's
+    ``default_backend``: compiled on a TPU, interpreted on ``cpu-interpret``.
     """
     if mode not in ("model", "measure"):
         raise ValueError(f"unknown mode {mode!r}")
@@ -144,6 +146,7 @@ def sweep_gemm(
         raise ValueError(f"unknown search {search!r}")
 
     hardware = resolve_profile(hardware)
+    backend = backend or hardware.default_backend
     space = space or TuningSpace()
     flops = 2.0 * m * k * n
     cands = list(space.candidates(hardware, dtype, m=m, k=k, n=n))
@@ -245,6 +248,7 @@ def sweep_flash_attention(
             points.append(SweepPoint(cfg, secs, flops / secs / 1e9, "model"))
     else:
         from repro.kernels.flash_attention import flash_attention
+        interpret = hardware.default_backend != ops.BACKEND_PALLAS_TPU
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (1, sq, batch_heads, d),
                               jnp.float32).astype(dtype)
@@ -255,7 +259,8 @@ def sweep_flash_attention(
         best_so_far = float("inf")
         for cfg, _est in selected:
             fn = jax.jit(lambda q, k, v, c=cfg: flash_attention(
-                q, k, v, causal=causal, bq=c.bq, bk=c.bk, interpret=True))
+                q, k, v, causal=causal, bq=c.bq, bk=c.bk,
+                interpret=interpret))
             prune_above = (best_so_far * prune_factor
                            if search == SEARCH_GUIDED and best_so_far < float("inf")
                            else None)

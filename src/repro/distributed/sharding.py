@@ -45,6 +45,19 @@ class ShardingRules:
         }
 
 
+def mesh_axis_label(mesh: Optional[Mesh]) -> Optional[str]:
+    """Compact topology label for keys/filenames: ``"data4xmodel2"``.
+
+    This is the mesh coordinate of mesh-keyed tuned entries
+    (``registry.mesh_hardware_key``) and of the per-mesh benchmark baseline
+    filenames, so the same string means the same topology everywhere.
+    None (no mesh) stays None.
+    """
+    if mesh is None:
+        return None
+    return "x".join(f"{name}{int(mesh.shape[name])}" for name in mesh.axis_names)
+
+
 def rules_for_mesh(mesh: Mesh, *, fsdp: bool = True,
                    sequence_parallel: bool = False) -> ShardingRules:
     axes = mesh.axis_names
@@ -55,6 +68,18 @@ def rules_for_mesh(mesh: Mesh, *, fsdp: bool = True,
         batch_axes=batch_axes,
         sequence_axis="model" if (sequence_parallel and "model" in axes) else None,
     )
+
+
+def serving_rules(mesh: Mesh) -> ShardingRules:
+    """Inference rules: tensor parallelism only, no FSDP.
+
+    Training shards weights over the data axes and re-gathers them per
+    step, amortized over a big batch.  Decode GEMMs are tiny (B x 1
+    tokens), so per-step weight all-gathers would serialize the loop:
+    serving replicates weights over the data axes and shards them only
+    over the tensor axis.
+    """
+    return rules_for_mesh(mesh, fsdp=False)
 
 
 def _axis_size(mesh: Mesh, axis) -> int:
@@ -70,13 +95,12 @@ def _axis_size(mesh: Mesh, axis) -> int:
 axis_size = _axis_size
 
 
-def spec_for_param(mesh: Mesh, rules: ShardingRules, spec: ParamSpec) -> P:
-    if len(spec.shape) <= 1:
-        return P()
+def mesh_axes_for(mesh: Mesh, rules: ShardingRules, shape, axes) -> list:
+    """Mesh axis (or None) per dim of an array with logical ``axes``."""
     mapping = rules.logical_map()
     used = set()
     out = []
-    for dim, axis_name in zip(spec.shape, spec.axes):
+    for dim, axis_name in zip(shape, axes):
         mesh_axis = mapping.get(axis_name)
         if (mesh_axis is None or mesh_axis in used
                 or dim % _axis_size(mesh, mesh_axis) != 0):
@@ -84,7 +108,23 @@ def spec_for_param(mesh: Mesh, rules: ShardingRules, spec: ParamSpec) -> P:
         else:
             out.append(mesh_axis)
             used.add(mesh_axis)
-    return P(*out)
+    return out
+
+
+def spec_for_param(mesh: Mesh, rules: ShardingRules, spec: ParamSpec) -> P:
+    if len(spec.shape) <= 1:
+        return P()
+    return P(*mesh_axes_for(mesh, rules, spec.shape, spec.axes))
+
+
+def weight_compute_axes(mesh: Mesh, rules: ShardingRules, shape,
+                        axes) -> Tuple[Optional[str], ...]:
+    """Mesh axis per dim of a matmul weight as a per-shard kernel consumes
+    it: the tensor-parallel split of :func:`spec_for_param`, with weight
+    shards over the batch axes (FSDP) gathered first."""
+    batch = set(rules.batch_axes)
+    return tuple(None if a in batch else a
+                 for a in mesh_axes_for(mesh, rules, shape, axes))
 
 
 def param_specs(mesh: Mesh, rules: ShardingRules, template):
@@ -118,35 +158,6 @@ def sharding_summary(mesh: Mesh, rules: ShardingRules, template) -> dict:
         key = str(tuple(spec_for_param(mesh, rules, spec)))
         counts[key] = counts.get(key, 0) + 1
     return counts
-
-
-def local_gemm_divisors(mesh: Mesh, rules: ShardingRules, template):
-    """``{(k, n): ((div_k, div_n), ...)}`` over the template's matmul weights.
-
-    A GEMM traced with *global* operand shapes runs per shard on the
-    *local* shapes ``(m/div_m, k/div_k, n/div_n)`` — under TP the tuned-tile
-    entry that actually matters is the local one.  The last two dims of each
-    >=2-D param are the ``(K, N)`` the single matmul entry point sees (scanned
-    stacks index their leading layer axis away), and the divisor of a dim is
-    the size of the mesh axes its spec shards it over.
-
-    Two weights can share a global ``(K, N)`` but shard it differently —
-    e.g. square attention projections, where ``wq`` is ``(embed, ff)`` but
-    ``wo`` is ``(ff, embed)`` — so every *distinct* divisor pair is returned
-    (sorted, deterministic) and consumers surface each local variant rather
-    than silently picking whichever leaf the pytree happens to visit first.
-    """
-    out: dict = {}
-    for spec in jax.tree_util.tree_leaves(template, is_leaf=is_spec):
-        if len(spec.shape) < 2:
-            continue
-        sp = spec_for_param(mesh, rules, spec)
-        padded = tuple(sp) + (None,) * (len(spec.shape) - len(tuple(sp)))
-        k, n = spec.shape[-2], spec.shape[-1]
-        dk = _axis_size(mesh, padded[len(spec.shape) - 2])
-        dn = _axis_size(mesh, padded[len(spec.shape) - 1])
-        out.setdefault((k, n), set()).add((dk, dn))
-    return {key: tuple(sorted(vals)) for key, vals in out.items()}
 
 
 # ---------------------------------------------------------------------------

@@ -41,17 +41,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
-
 NEG_INF = -1e30
 #: scores at/below this are treated as masked when guarding exp() — far below
 #: any reachable logit, far above NEG_INF
 _MASKED_BELOW = -1e28
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, kvs_ref, o_ref, m_scr, l_scr, acc_scr,
+def _flash_kernel(kvs_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                   *, n_kv: int, scale: float, causal: bool,
                   causal_offset: int, bq: int, bk: int):
+    bi = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -71,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, kvs_ref, o_ref, m_scr, l_scr, acc_scr,
         rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         s = jnp.where(cols <= rows + causal_offset, s, NEG_INF)
     # ragged left-padding: columns before this row's kv_start are invalid
-    s = jnp.where(cols >= kvs_ref[0, 0], s, NEG_INF)
+    s = jnp.where(cols >= kvs_ref[bi], s, NEG_INF)
 
     m_prev = m_scr[...]                          # (bq, 1)
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -137,26 +136,31 @@ def flash_attention_bhsd(
         _flash_kernel, n_kv=n_kv, scale=scale, causal=causal,
         causal_offset=skv_p - sq_p, bq=bq, bk=bk)
 
-    out = pl.pallas_call(
-        kernel,
+    # kv_start rides as a scalar-prefetch operand in SMEM: a (1, 1) VMEM
+    # block of a (BH, 1) array breaks Mosaic's (8, 128) block tiling.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, j, kvs: (b, i, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j, kvs: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j, kvs: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j, kvs: (b, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, kv_start[:, None])
+    )(kv_start, q, k, v)
     return out[:, pq:, :] if pq else out
 
 

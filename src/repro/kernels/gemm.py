@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
-
 from repro.kernels.ref import apply_epilogue
 
 
@@ -116,9 +114,11 @@ def gemm_pallas(
         operands.append(c)
     has_bias = bias is not None
     if has_bias:
+        # A (1, N) row with (1, bn) blocks: Mosaic tiles rank-1 bf16 blocks
+        # in 256-lane units, so a rank-1 (bn,) block with bn=128 is refused.
         assert bias.shape == (n,), bias.shape
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j, kk: (j,)))
-        operands.append(bias)
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
+        operands.append(bias.reshape(1, n))
 
     kernel = functools.partial(
         _gemm_kernel, n_k=n_k, alpha=alpha, beta=beta,
@@ -127,7 +127,7 @@ def gemm_pallas(
 
     # Grid iteration order: k innermost (revisits the same C tile) so the
     # accumulator scratch carries across k steps; i/j are parallel.
-    compiler_params = pallas_compat.CompilerParams(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
 

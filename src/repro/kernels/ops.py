@@ -70,7 +70,15 @@ def gemm(
 
     if config is None:
         raise ValueError("pallas backends need a TileConfig (use core.registry)")
-    m, k = a.shape
+    return _pallas_gemm(a, b, c, bias, config,
+                        backend == BACKEND_PALLAS_INTERPRET, alpha, beta,
+                        activation, out_dtype)
+
+
+def _pallas_forward(a, b, c, bias, config, interpret, alpha, beta,
+                    activation, out_dtype):
+    """Pad to the tile grid, run the kernel, slice the result back."""
+    m, _ = a.shape
     _, n = b.shape
     bm, bk, bn = config.bm, config.bk, config.bn
     a_p = _pad_to(a, (bm, bk))
@@ -81,12 +89,52 @@ def gemm(
         a_p, b_p, c_p,
         bm=bm, bk=bk, bn=bn,
         alpha=alpha, beta=beta, bias=bias_p, activation=activation,
-        out_dtype=out_dtype,
-        interpret=(backend == BACKEND_PALLAS_INTERPRET),
+        out_dtype=out_dtype, interpret=interpret,
     )
     if out.shape != (m, n):
         out = out[:m, :n]
     return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _pallas_gemm(a, b, c, bias, config, interpret, alpha, beta, activation,
+                 out_dtype):
+    return _pallas_forward(a, b, c, bias, config, interpret, alpha, beta,
+                           activation, out_dtype)
+
+
+def _pallas_gemm_fwd(a, b, c, bias, config, interpret, alpha, beta,
+                     activation, out_dtype):
+    out = _pallas_forward(a, b, c, bias, config, interpret, alpha, beta,
+                          activation, out_dtype)
+    return out, (a, b, c, bias)
+
+
+def _pallas_gemm_bwd(config, interpret, alpha, beta, activation, out_dtype,
+                     res, g):
+    """Backward of ``act(alpha * A @ B + beta * C + bias)`` as two GEMMs
+    through the same kernel: ``dA = alpha * g' @ B^T`` and
+    ``dB = alpha * A^T @ g'``, where ``g'`` is ``g`` through the
+    activation's derivative.  With an activation the pre-activation is
+    recomputed by the kernel (a third GEMM) instead of being stored."""
+    a, b, c, bias = res
+    g = g.astype(jnp.float32)
+    if activation is not None:
+        z = _pallas_forward(a, b, c, bias, config, interpret, alpha, beta,
+                            None, jnp.float32)
+        _, act_vjp = jax.vjp(_ref.ACTIVATIONS[activation], z)
+        (g,) = act_vjp(g)
+    gk = g.astype(jnp.result_type(a.dtype, b.dtype))
+    da = _pallas_forward(gk, b.T, None, None, config, interpret, alpha, 0.0,
+                         None, a.dtype)
+    db = _pallas_forward(a.T, gk, None, None, config, interpret, alpha, 0.0,
+                         None, b.dtype)
+    dc = (beta * g).astype(c.dtype) if c is not None else None
+    dbias = g.sum(axis=0).astype(bias.dtype) if bias is not None else None
+    return da, db, dc, dbias
+
+
+_pallas_gemm.defvjp(_pallas_gemm_fwd, _pallas_gemm_bwd)
 
 
 def _xla_gemm(a, b, c=None, *, alpha, beta, bias, activation, out_dtype,

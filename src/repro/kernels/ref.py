@@ -15,7 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-_ACTIVATIONS = {
+ACTIVATIONS = {
     None: lambda x: x,
     "relu": jax.nn.relu,
     "gelu": jax.nn.gelu,
@@ -27,9 +27,9 @@ _ACTIVATIONS = {
 def apply_epilogue(out_f32, bias=None, activation: Optional[str] = None):
     if bias is not None:
         out_f32 = out_f32 + bias.astype(jnp.float32)
-    if activation not in _ACTIVATIONS:
+    if activation not in ACTIVATIONS:
         raise ValueError(f"unknown activation {activation!r}")
-    return _ACTIVATIONS[activation](out_f32)
+    return ACTIVATIONS[activation](out_f32)
 
 
 def gemm_ref(

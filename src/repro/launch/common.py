@@ -11,13 +11,69 @@ text).  This module is the single declaration:
 * :func:`deprecated_flag` — registers a retired flag that still parses:
   using it warns once and forwards its value onto the replacement, so old
   command lines keep working one release while printing their migration.
+* :func:`enable_compile_cache` — JAX's persistent compilation cache, which
+  every launcher turns on first thing in ``main()``;
+* :func:`apply_latency_hiding_flags` — the hardware profile's
+  collective-overlap compiler flags, set before a mesh run's backend starts.
 
 Drivers call these, then add their driver-specific flags on top.
 """
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import warnings
+from typing import List, Optional
+
+#: the cache's fixed in-repo home when the caller places none (listed in
+#: .gitignore).  A fixed path, because the path is part of the cache key.
+DEFAULT_COMPILE_CACHE = (pathlib.Path(__file__).resolve().parents[3]
+                         / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself, and no other
+    directory is set in code.  Otherwise the cache lives at
+    :data:`DEFAULT_COMPILE_CACHE`.  Call it at the start of a ``main()``,
+    never at import (tests leave the cache off).
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
+
+
+def apply_latency_hiding_flags(hardware: Optional[str]) -> List[str]:
+    """Set the named profile's latency-hiding compiler flags; returns the
+    flags added.
+
+    The runtime reads its flags once, when the backend starts, so a
+    launcher calls this before its first device touch, and only with a
+    profile it was named (``--hardware`` or ``$REPRO_HARDWARE``): detecting
+    the profile would start the backend first.  TPU flags go to
+    ``LIBTPU_INIT_ARGS``, where libtpu reads them (``XLA_FLAGS`` refuses
+    ``xla_tpu_*`` flags as unknown and aborts); other backends' go to
+    ``XLA_FLAGS``.  A flag already set is left as the caller set it.
+    """
+    from repro.core.hardware import (HARDWARE_ENV, PLATFORM_TPU,
+                                     canonical_name, find_profile)
+    name = hardware or os.environ.get(HARDWARE_ENV)
+    prof = find_profile(canonical_name(name)) if name else None
+    if prof is None:
+        return []
+    env = ("LIBTPU_INIT_ARGS" if prof.platform == PLATFORM_TPU
+           else "XLA_FLAGS")
+    current = os.environ.get(env, "")
+    added = [f for f in prof.xla_latency_flags
+             if f.split("=")[0] not in current]
+    if added:
+        os.environ[env] = " ".join(filter(None, [current] + added))
+    return added
 
 
 def add_common_args(ap: argparse.ArgumentParser) -> None:

@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ^ MUST be the first two lines: jax locks the device count on first init.
-# The 512 host devices exist ONLY for this dry-run process (16x16 single-pod
-# and 2x16x16 multi-pod production meshes); tests/benches see 1 device.
 """Multi-pod dry-run: lower + compile every (architecture x input-shape)
 cell on the production meshes and record memory/cost/collective analysis.
 
@@ -18,6 +12,7 @@ emitted for long_500k on pure full-attention archs (DESIGN.md §4).
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Optional
@@ -157,7 +152,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
 
     try:
         cost = compiled.cost_analysis()
-        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
         cost_rec = {"flops": float(cost.get("flops", -1)),
                     "bytes_accessed": float(cost.get("bytes accessed", -1))}
     except Exception as e:
@@ -214,6 +208,12 @@ def main() -> None:
     ap.add_argument("--force", action="store_true", help="recompute existing")
     ap.add_argument("--list", action="store_true")
     args = ap.parse_args()
+    # 512 host devices for the 16x16 single-pod and 2x16x16 multi-pod
+    # production meshes.  Set before the first device touch (jax reads
+    # XLA_FLAGS once, at backend init), and only in this process.
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS", ""),
+        "--xla_force_host_platform_device_count=512"]))
 
     archs = [args.arch] if args.arch else list(ARCHITECTURES)
     shapes = [args.shape] if args.shape else list(SHAPES)

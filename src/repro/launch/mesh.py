@@ -16,69 +16,15 @@ CI exercises multi-device meshes on a CPU host via
 """
 from __future__ import annotations
 
-import os
-import warnings
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import numpy as np
 
+from repro.distributed.sharding import mesh_axis_label
+
 #: axis names the sharding rules understand (distributed/sharding.py)
 MESH_AXES = ("pod", "data", "model")
-
-
-def mesh_axis_label(mesh: Optional[jax.sharding.Mesh]) -> Optional[str]:
-    """Compact topology label for keys/filenames: ``"data4xmodel2"``.
-
-    This is the mesh coordinate of mesh-keyed tuned entries
-    (``registry.mesh_hardware_key``) and of the per-mesh benchmark baseline
-    filenames, so the same string means the same topology everywhere.
-    None (no mesh) stays None.
-    """
-    if mesh is None:
-        return None
-    return "x".join(f"{name}{int(mesh.shape[name])}" for name in mesh.axis_names)
-
-
-def _backends_initialized() -> bool:
-    """True once jax has instantiated a backend (XLA_FLAGS edits are moot)."""
-    try:
-        from jax._src import xla_bridge
-        return bool(xla_bridge.backends_are_initialized())
-    except Exception:   # pragma: no cover - private-API drift
-        return True     # can't tell -> assume too late, never lie "applied"
-
-
-def apply_latency_hiding_flags(hardware: Optional[str] = None,
-                               ) -> Dict[str, object]:
-    """Append the hardware profile's latency-hiding XLA flags to XLA_FLAGS.
-
-    XLA reads ``XLA_FLAGS`` once at backend init, so this only works before
-    jax has built a backend — launchers call it (via :func:`build_mesh`)
-    before touching devices.  Flags already present (user override) are left
-    alone; if the backend is already live the call warns and applies
-    nothing.  Returns provenance for stats/bench artifacts:
-    ``{"hardware", "applied": [...], "skipped": [...]}``.
-    """
-    from repro.core.hardware import resolve_hardware, find_profile
-    name = resolve_hardware(hardware)
-    prof = find_profile(name)
-    flags: Tuple[str, ...] = prof.xla_latency_flags if prof else ()
-    current = os.environ.get("XLA_FLAGS", "")
-    applied, skipped = [], []
-    missing = [f for f in flags if f.split("=")[0] not in current]
-    skipped += [f for f in flags if f.split("=")[0] in current]
-    if missing and _backends_initialized():
-        warnings.warn(
-            "jax backend already initialized; latency-hiding XLA flags for "
-            f"{name!r} cannot take effect this process: {missing}",
-            stacklevel=2)
-        skipped += missing
-        missing = []
-    if missing:
-        os.environ["XLA_FLAGS"] = " ".join(filter(None, [current] + missing))
-        applied = missing
-    return {"hardware": name, "applied": applied, "skipped": skipped}
 
 
 def parse_mesh_spec(spec: str) -> Dict[str, int]:
@@ -117,23 +63,16 @@ def parse_mesh_spec(spec: str) -> Dict[str, int]:
     return out
 
 
-def build_mesh(spec: Optional[str], *, devices=None,
-               hardware: Optional[str] = None) -> Optional[jax.sharding.Mesh]:
+def build_mesh(spec: Optional[str], *,
+               devices=None) -> Optional[jax.sharding.Mesh]:
     """Build a Mesh from a ``--mesh`` spec string (None/"" -> no mesh).
 
     ``"auto"`` puts every visible device on the ``data`` axis.  An explicit
     spec may use a *subset* of the visible devices (the first ``prod(sizes)``
     in ``jax.devices()`` order), so ``data=2`` works on an 8-device host.
-
-    Passing ``hardware`` applies that profile's latency-hiding XLA flags
-    *before* the first device touch (the ``jax.devices()`` below is usually
-    what initializes the backend), so a launcher gets async collectives by
-    building its mesh — no flag plumbing of its own.
     """
     if not spec:
         return None
-    if hardware is not None:
-        apply_latency_hiding_flags(hardware)
     devices = list(devices if devices is not None else jax.devices())
     if spec.strip() == "auto":
         sizes = {"data": len(devices)}
@@ -164,10 +103,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure data parallelism across the slow inter-pod (DCN) domain."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small CPU mesh for in-process smoke tests (requires the host platform
     to expose data*model devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
+
+
+def _auto(n: int):
+    """Auto axis types: ``jax.make_mesh`` defaults to Explicit axes, which
+    ``with_sharding_constraint`` (``distributed.ctx.constrain``) rejects."""
+    return (jax.sharding.AxisType.Auto,) * n

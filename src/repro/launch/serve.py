@@ -29,7 +29,9 @@ from repro.configs.catalog import get_config
 from repro.core import tuning_db
 from repro.core.hardware import find_profile, resolve_hardware
 from repro.core.registry import GLOBAL_REGISTRY
-from repro.launch.common import add_common_args, add_serving_args
+from repro.launch.common import (add_common_args, add_serving_args,
+                                 apply_latency_hiding_flags,
+                                 enable_compile_cache)
 from repro.models import build_model
 from repro.serve import Engine, Request, ServeConfig, Server
 
@@ -82,17 +84,21 @@ def main() -> None:
     add_common_args(ap)
     args = ap.parse_args()
 
+    if args.mesh:
+        # before the first device touch: the runtime reads its flags once
+        print(f"[flags] {apply_latency_hiding_flags(args.hardware)}")
+    print(f"[cache] compile cache at {enable_compile_cache()}")
     hardware = resolve_hardware(args.hardware)
     prof = find_profile(hardware)
     print(f"[hw] profile={hardware} "
           f"platform={prof.platform if prof else 'unknown'} "
           f"({'flag' if args.hardware else 'detected'})")
-    mesh = None
+    mesh = rules = None
     if args.mesh:
+        from repro.distributed.sharding import serving_rules
         from repro.launch.mesh import build_mesh, describe_mesh
-        # hardware= applies the profile's latency-hiding XLA flags before
-        # the first device touch (async collectives for the decode loop)
-        mesh = build_mesh(args.mesh, hardware=hardware)
+        mesh = build_mesh(args.mesh)
+        rules = serving_rules(mesh)
         print(f"[mesh] {describe_mesh(mesh)}")
 
     loaded = tuning_db.load_all(GLOBAL_REGISTRY, args.tuned_dir)
@@ -108,7 +114,9 @@ def main() -> None:
         import dataclasses
         cfg = dataclasses.replace(cfg, attention_impl=args.attn_impl)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # On a mesh every shard is initialised on its own device, by the same
+    # inference rules the engine serves with (no full copy on device 0).
+    params = model.init(jax.random.PRNGKey(0), mesh=mesh, rules=rules)
 
     prompts = [[int(t) % cfg.vocab_size for t in p.split(",")]
                for p in args.prompts.split(";")]

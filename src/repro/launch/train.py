@@ -27,7 +27,9 @@ from repro.core.hardware import resolve_hardware
 from repro.core.registry import GLOBAL_REGISTRY
 from repro.data import DataConfig, TokenPipeline
 from repro.distributed import sharding as sh
-from repro.launch.common import add_common_args, deprecated_flag
+from repro.launch.common import (add_common_args,
+                                 apply_latency_hiding_flags, deprecated_flag,
+                                 enable_compile_cache)
 from repro.launch.mesh import build_mesh, describe_mesh
 from repro.models import build_model
 from repro.optim import AdamW, warmup_cosine
@@ -62,6 +64,10 @@ def main() -> None:
         if data * model_ax > 1:
             args.mesh = f"data={data},model={model_ax}"
 
+    if args.mesh:
+        # before the first device touch: the runtime reads its flags once
+        print(f"[flags] {apply_latency_hiding_flags(args.hardware)}")
+    print(f"[cache] compile cache at {enable_compile_cache()}")
     hardware = resolve_hardware(args.hardware)
     print(f"[hw] profile={hardware} "
           f"({'flag' if args.hardware else 'detected'})")
@@ -84,9 +90,7 @@ def main() -> None:
 
     mesh = rules = None
     if args.mesh:
-        # hardware= applies the profile's latency-hiding XLA flags before
-        # the first device touch (overlap grad all-reduces with compute)
-        mesh = build_mesh(args.mesh, hardware=hardware)
+        mesh = build_mesh(args.mesh)
         rules = sh.rules_for_mesh(mesh)
         print(f"[mesh] {describe_mesh(mesh)} rules={rules}")
 

@@ -31,7 +31,8 @@ def mamba_lm_template(cfg: ModelConfig):
         "blocks": T._stack_template(_mamba_block_template(cfg), cfg.num_layers),
         "ln_f": L.norm_template(cfg.d_model, cfg.norm),
     } | ({} if cfg.tie_embeddings else
-         {"lm_head": ParamSpec((cfg.d_model, cfg.vocab_size), ("embed", "vocab"))})
+         {"lm_head": ParamSpec((cfg.d_model, cfg.vocab_size),
+                              T.UNEMBED_AXES)})
 
 
 def _mamba_block_template(cfg: ModelConfig):
@@ -54,7 +55,7 @@ def zamba_template(cfg: ModelConfig):
             T._stack_template(_mamba_block_template(cfg), cfg.attn_period),
             units),
         "ln_f": L.norm_template(cfg.d_model, cfg.norm),
-        "lm_head": ParamSpec((cfg.d_model, cfg.vocab_size), ("embed", "vocab")),
+        "lm_head": ParamSpec((cfg.d_model, cfg.vocab_size), T.UNEMBED_AXES),
     }
 
 
@@ -165,7 +166,9 @@ def prefill(cfg: ModelConfig, params, batch, cache):
     # real token exactly the zero init — see ssm_block(valid_mask=...).
     valid = None if kv_start is None else (
         jnp.arange(s, dtype=jnp.int32)[None, :] >= kv_start[:, None])
-    offset = jnp.int32(0)
+    # A Python 0, not a traced one: under jit a jnp scalar is a tracer, and
+    # attention routes to the flash kernel only for a static zero offset.
+    offset = 0
 
     if cfg.family == "ssm":
         # Full-sequence SSD pass; the chunked kernel also yields the exact

@@ -19,6 +19,13 @@ from repro.models.params import ParamSpec
 
 logger = logging.getLogger(__name__)
 
+#: logical axes of the two matmul weight layouts: column parallel (the
+#: output dim splits over the tensor axis) and row parallel (the contracted
+#: dim splits).  Templates declare them and matmul calls pass them on, so a
+#: per-shard kernel on a mesh knows each weight's split.
+COL = ("embed", "ff")
+ROW = ("ff", "embed")
+
 # ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------
@@ -86,10 +93,10 @@ class AttnDims:
 def attention_template(d_model: int, dims: AttnDims, qkv_bias: bool = False):
     h, kv, hd = dims.num_heads, dims.num_kv_heads, dims.head_dim
     t = {
-        "wq": ParamSpec((d_model, h * hd), ("embed", "ff")),
-        "wk": ParamSpec((d_model, kv * hd), ("embed", "ff")),
-        "wv": ParamSpec((d_model, kv * hd), ("embed", "ff")),
-        "wo": ParamSpec((h * hd, d_model), ("ff", "embed")),
+        "wq": ParamSpec((d_model, h * hd), COL),
+        "wk": ParamSpec((d_model, kv * hd), COL),
+        "wv": ParamSpec((d_model, kv * hd), COL),
+        "wo": ParamSpec((h * hd, d_model), ROW),
     }
     if qkv_bias:
         t["bq"] = ParamSpec((h * hd,), ("ff",), init="zeros")
@@ -226,8 +233,10 @@ def _log_flash_fallback(reason: str) -> None:
 def cross_kv(params, src: jax.Array, dims: AttnDims):
     """Project encoder/image embeddings to the (static) cross K/V once."""
     b = src.shape[0]
-    k = matmul(src, params["wk"], bias=params.get("bk")).reshape(b, -1, dims.num_kv_heads, dims.head_dim)
-    v = matmul(src, params["wv"], bias=params.get("bv")).reshape(b, -1, dims.num_kv_heads, dims.head_dim)
+    k = matmul(src, params["wk"], bias=params.get("bk"), w_axes=COL
+               ).reshape(b, -1, dims.num_kv_heads, dims.head_dim)
+    v = matmul(src, params["wv"], bias=params.get("bv"), w_axes=COL
+               ).reshape(b, -1, dims.num_kv_heads, dims.head_dim)
     return k, v
 
 
@@ -279,7 +288,7 @@ def attention(
         else:
             _log_flash_fallback(reason)
 
-    q = matmul(x, params["wq"], bias=params.get("bq"))
+    q = matmul(x, params["wq"], bias=params.get("bq"), w_axes=COL)
     q = q.reshape(b, s, h, hd)
 
     if kv_override is not None:
@@ -287,10 +296,13 @@ def attention(
         qg = q.reshape(b, s, kvh, dims.group, hd)
         out = _sdpa_chunked(qg, k, v, causal=False, q_offset=0,
                             kv_len=None, chunk=q_chunk, p_dtype=p_dtype)
-        return matmul(out.reshape(b, s, h * hd), params["wo"]), None
+        return matmul(out.reshape(b, s, h * hd), params["wo"],
+                      w_axes=ROW), None
 
-    k = matmul(x, params["wk"], bias=params.get("bk")).reshape(b, s, kvh, hd)
-    v = matmul(x, params["wv"], bias=params.get("bv")).reshape(b, s, kvh, hd)
+    k = matmul(x, params["wk"], bias=params.get("bk"), w_axes=COL
+               ).reshape(b, s, kvh, hd)
+    v = matmul(x, params["wv"], bias=params.get("bv"), w_axes=COL
+               ).reshape(b, s, kvh, hd)
     if rope_theta:
         q = apply_rope(q, positions, theta=rope_theta, fraction=rope_fraction)
         k = apply_rope(k, positions, theta=rope_theta, fraction=rope_fraction)
@@ -327,14 +339,15 @@ def attention(
         kf, vf = (k[:, :s], v[:, :s]) if kv_cache is not None else (k, v)
         from repro.core import flash_attention as tuned_flash
         out = tuned_flash(q, kf, vf, causal=causal, kv_start=kv_start)
-        return matmul(out.reshape(b, s, h * hd), params["wo"]), new_cache
+        return (matmul(out.reshape(b, s, h * hd), params["wo"], w_axes=ROW),
+                new_cache)
 
     qg = q.reshape(b, s, kvh, dims.group, hd)
     out = _sdpa_chunked(qg, k, v, causal=causal, q_offset=q_offset,
                         kv_len=kv_len, chunk=q_chunk, p_dtype=p_dtype,
                         kv_start=kv_start)
     out = out.reshape(b, s, h * hd)
-    return matmul(out, params["wo"]), new_cache
+    return matmul(out, params["wo"], w_axes=ROW), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -343,28 +356,29 @@ def attention(
 
 def mlp_template(d_model: int, d_ff: int):
     return {
-        "w_gate": ParamSpec((d_model, d_ff), ("embed", "ff")),
-        "w_up": ParamSpec((d_model, d_ff), ("embed", "ff")),
-        "w_down": ParamSpec((d_ff, d_model), ("ff", "embed")),
+        "w_gate": ParamSpec((d_model, d_ff), COL),
+        "w_up": ParamSpec((d_model, d_ff), COL),
+        "w_down": ParamSpec((d_ff, d_model), ROW),
     }
 
 
 def mlp(params, x: jax.Array) -> jax.Array:
-    gate = matmul(x, params["w_gate"], activation="silu")
-    up = matmul(x, params["w_up"])
-    return matmul(gate * up, params["w_down"])
+    gate = matmul(x, params["w_gate"], activation="silu", w_axes=COL)
+    up = matmul(x, params["w_up"], w_axes=COL)
+    return matmul(gate * up, params["w_down"], w_axes=ROW)
 
 
 def mlp_gelu_template(d_model: int, d_ff: int):
     """Whisper-style 2-matrix GELU MLP (with biases)."""
     return {
-        "w_up": ParamSpec((d_model, d_ff), ("embed", "ff")),
+        "w_up": ParamSpec((d_model, d_ff), COL),
         "b_up": ParamSpec((d_ff,), ("ff",), init="zeros"),
-        "w_down": ParamSpec((d_ff, d_model), ("ff", "embed")),
+        "w_down": ParamSpec((d_ff, d_model), ROW),
         "b_down": ParamSpec((d_model,), ("embed",), init="zeros"),
     }
 
 
 def mlp_gelu(params, x: jax.Array) -> jax.Array:
-    h = matmul(x, params["w_up"], bias=params["b_up"], activation="gelu")
-    return matmul(h, params["w_down"], bias=params["b_down"])
+    h = matmul(x, params["w_up"], bias=params["b_up"], activation="gelu",
+               w_axes=COL)
+    return matmul(h, params["w_down"], bias=params["b_down"], w_axes=ROW)

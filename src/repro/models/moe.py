@@ -24,11 +24,14 @@ from repro.core import einsum, matmul
 from repro.distributed.ctx import constrain
 from repro.models.params import ParamSpec
 
+#: logical axes of the router weight: replicated (the expert dim is small)
+ROUTER_AXES = ("embed", None)
+
 
 def moe_template(d_model: int, d_ff: int, num_experts: int):
     e = num_experts
     return {
-        "router": ParamSpec((d_model, e), ("embed", None), scale=0.02),
+        "router": ParamSpec((d_model, e), ROUTER_AXES, scale=0.02),
         "w_gate": ParamSpec((e, d_model, d_ff), ("expert", "embed", "ff")),
         "w_up": ParamSpec((e, d_model, d_ff), ("expert", "embed", "ff")),
         "w_down": ParamSpec((e, d_ff, d_model), ("expert", "ff", "embed")),
@@ -85,7 +88,7 @@ def moe_layer(params, x: jax.Array, *, top_k: int, num_experts: int,
     """x: (B, S, D) -> (out, aux_loss).  Routing vmapped over batch groups."""
     b, s, d = x.shape
     cap = capacity(s, num_experts, top_k, capacity_factor)
-    logits = matmul(x, params["router"])                           # (B, S, E)
+    logits = matmul(x, params["router"], w_axes=ROUTER_AXES)      # (B, S, E)
 
     bufs, routes = jax.vmap(
         lambda xg, lg: _route_group(xg, lg, top_k=top_k,

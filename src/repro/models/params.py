@@ -47,28 +47,33 @@ def init_params(template, key: jax.Array, default_dtype: str = "float32",
                 shardings=None):
     """Materialize random parameters from a template pytree.
 
-    ``shardings`` (a pytree of ``NamedSharding`` aligned with the template,
-    e.g. from ``distributed.sharding.param_shardings``) places every leaf on
-    its mesh shards — values are bit-identical to the unsharded init, only
-    the layout differs, which is what keeps 1-device vs N-device runs
-    token-for-token comparable.
+    One jitted program draws every leaf.  ``shardings`` (a pytree of
+    ``NamedSharding`` aligned with the template, e.g. from
+    ``distributed.sharding.param_shardings``) become its output shardings:
+    every device draws only its own shards, so a model larger than one
+    device's memory never lands whole on device 0.  Values are bit-identical
+    to the unsharded init, only the layout differs, which is what keeps
+    1-device vs N-device runs token-for-token comparable.
     """
-    def init_leaf(path, spec: ParamSpec):
-        dtype = jnp.dtype(spec.dtype or default_dtype)
-        if spec.init == "zeros":
-            return jnp.zeros(spec.shape, dtype)
-        if spec.init == "ones":
-            return jnp.ones(spec.shape, dtype)
-        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else max(spec.shape[-1], 1)
-        scale = spec.scale if spec.scale is not None else 1.0 / math.sqrt(fan_in)
-        k = _leaf_key(key, _path_str(path))
-        return (scale * jax.random.normal(k, spec.shape, jnp.float32)).astype(dtype)
+    def build(key):
+        def init_leaf(path, spec: ParamSpec):
+            dtype = jnp.dtype(spec.dtype or default_dtype)
+            if spec.init == "zeros":
+                return jnp.zeros(spec.shape, dtype)
+            if spec.init == "ones":
+                return jnp.ones(spec.shape, dtype)
+            fan_in = (spec.shape[-2] if len(spec.shape) >= 2
+                      else max(spec.shape[-1], 1))
+            scale = (spec.scale if spec.scale is not None
+                     else 1.0 / math.sqrt(fan_in))
+            k = _leaf_key(key, _path_str(path))
+            return (scale * jax.random.normal(k, spec.shape, jnp.float32)
+                    ).astype(dtype)
 
-    params = jax.tree_util.tree_map_with_path(init_leaf, template,
-                                              is_leaf=is_spec)
-    if shardings is not None:
-        params = jax.device_put(params, shardings)
-    return params
+        return jax.tree_util.tree_map_with_path(init_leaf, template,
+                                                is_leaf=is_spec)
+
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 def abstract_params(template, default_dtype: str = "float32"):

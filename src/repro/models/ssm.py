@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import einsum, matmul
 from repro.configs.base import ModelConfig
+from repro.models.layers import COL, ROW
 from repro.models.params import ParamSpec
 
 
@@ -33,14 +34,14 @@ def ssm_dims(cfg: ModelConfig):
 def ssm_template(cfg: ModelConfig):
     d_inner, n_heads, conv_dim, d_in_proj = ssm_dims(cfg)
     return {
-        "in_proj": ParamSpec((cfg.d_model, d_in_proj), ("embed", "ff")),
+        "in_proj": ParamSpec((cfg.d_model, d_in_proj), COL),
         "conv_w": ParamSpec((cfg.ssm_conv, conv_dim), (None, "ff"), scale=0.5),
         "conv_b": ParamSpec((conv_dim,), ("ff",), init="zeros"),
         "A_log": ParamSpec((n_heads,), (None,), init="zeros"),
         "D": ParamSpec((n_heads,), (None,), init="ones"),
         "dt_bias": ParamSpec((n_heads,), (None,), init="zeros"),
         "norm": ParamSpec((d_inner,), ("ff",), init="ones"),
-        "out_proj": ParamSpec((d_inner, cfg.d_model), ("ff", "embed")),
+        "out_proj": ParamSpec((d_inner, cfg.d_model), ROW),
     }
 
 
@@ -95,7 +96,7 @@ def ssm_block(params, x: jax.Array, cfg: ModelConfig,
         l -= 1
     nc = s // l
 
-    z, xBC, dt = _split_zxbcdt(cfg, matmul(x, params["in_proj"]))
+    z, xBC, dt = _split_zxbcdt(cfg, matmul(x, params["in_proj"], w_axes=COL))
     if valid_mask is not None:
         xBC = jnp.where(valid_mask[..., None], xBC, 0)
     xBC_pre = xBC
@@ -144,7 +145,7 @@ def ssm_block(params, x: jax.Array, cfg: ModelConfig,
     y = y + params["D"].astype(jnp.float32)[None, None, :, None] * xs.reshape(b, s, n_heads, p).astype(jnp.float32)
 
     y = _gated_norm(params["norm"], y.reshape(b, s, d_inner).astype(x.dtype), z, cfg.norm_eps)
-    out = matmul(y, params["out_proj"])
+    out = matmul(y, params["out_proj"], w_axes=ROW)
     if not return_state:
         return out
     final_state = {
@@ -169,7 +170,7 @@ def ssm_decode_step(params, x: jax.Array, state, cfg: ModelConfig):
     d_inner, n_heads, conv_dim, _ = ssm_dims(cfg)
     p, n = cfg.ssm_head_dim, cfg.ssm_state
 
-    z, xBC, dt = _split_zxbcdt(cfg, matmul(x[:, 0], params["in_proj"]))
+    z, xBC, dt = _split_zxbcdt(cfg, matmul(x[:, 0], params["in_proj"], w_axes=COL))
     window = jnp.concatenate([state["conv"], xBC[:, None, :]], axis=1)    # (B,K,C)
     conv_out = jax.nn.silu((window * params["conv_w"][None]).sum(1) + params["conv_b"])
     new_conv = window[:, 1:]
@@ -186,5 +187,5 @@ def ssm_decode_step(params, x: jax.Array, state, cfg: ModelConfig):
     y = y + params["D"].astype(jnp.float32)[None, :, None] * xh
 
     y = _gated_norm(params["norm"], y.reshape(b, d_inner).astype(x.dtype), z, cfg.norm_eps)
-    out = matmul(y, params["out_proj"])[:, None, :]
+    out = matmul(y, params["out_proj"], w_axes=ROW)[:, None, :]
     return out, {"conv": new_conv, "ssm": new_ssm}

@@ -21,6 +21,11 @@ from repro.models import moe as M
 from repro.models.params import ParamSpec
 
 
+#: logical axes of the unembedding weight (``lm_head``, or the tied
+#: embedding table transposed)
+UNEMBED_AXES = ("embed", "vocab")
+
+
 def attn_dims(cfg: ModelConfig) -> L.AttnDims:
     return L.AttnDims(cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim)
 
@@ -68,7 +73,7 @@ def template(cfg: ModelConfig):
     }
     if not cfg.tie_embeddings:
         t["lm_head"] = ParamSpec((cfg.d_model, cfg.vocab_size),
-                                 ("embed", "vocab"))
+                                 UNEMBED_AXES)
     if cfg.family == "vlm":
         units = cfg.num_layers // cfg.cross_attn_period
         per_unit = cfg.cross_attn_period - 1
@@ -193,8 +198,8 @@ def _embed(cfg, params, tokens):
 def _unembed(cfg, params, x):
     x = L.apply_norm(params["ln_f"], x, eps=cfg.norm_eps)
     w = params["embedding"].T if cfg.tie_embeddings else params["lm_head"]
-    return constrain(matmul(x, w.astype(x.dtype), out_dtype=jnp.float32),
-                     "logits")
+    return constrain(matmul(x, w.astype(x.dtype), out_dtype=jnp.float32,
+                            w_axes=UNEMBED_AXES), "logits")
 
 
 def _positions(batch: int, seq: int, offset=0):
@@ -282,7 +287,9 @@ def prefill(cfg: ModelConfig, params, batch, cache):
     kv_start = batch.get("kv_start")
     x = _embed(cfg, params, tokens)
     pos = _positions(b, s) if kv_start is None else _ragged_positions(s, kv_start)
-    offset = jnp.int32(0)
+    # A Python 0, not a traced one: under jit a jnp scalar is a tracer, and
+    # attention routes to the flash kernel only for a static zero offset.
+    offset = 0
     if cfg.family == "vlm":
         cache = dict(cache)
         cache["cross"] = _vlm_cross_cache(cfg, params, batch["image_embeds"])

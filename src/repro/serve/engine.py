@@ -66,6 +66,7 @@ bit-identical float paths — the basis of the ragged-batch parity guarantee.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Callable, Dict, List, Optional, Sequence, Union
@@ -232,17 +233,12 @@ class Engine:
         self.rules = None
         if mesh is not None:
             from repro.distributed import sharding as sh
-            # Inference rules: no FSDP.  Training shards weights over the
-            # data axes and re-gathers them per step — amortized over a big
-            # batch.  Decode GEMMs are tiny (B x 1 tokens), so per-step
-            # weight all-gathers SERIALIZE the loop (profiling showed them
-            # dominating decode wall-clock at 0.54x of the sync baseline).
-            # Serving therefore replicates weights over the data axes and
-            # shards them only over the tensor axis (classic inference TP);
-            # explicit ambient rules still win for callers that know better.
-            self.rules = rules or sh.rules_for_mesh(mesh, fsdp=False)
-            # Re-place params by the rules (no-op layout change on values:
-            # sharded and single-device engines stay token-for-token equal).
+            # Inference rules (TP only, no FSDP); explicit ambient rules
+            # still win for callers that know better.
+            self.rules = rules or sh.serving_rules(mesh)
+            # Re-place params by the rules (no-op when they were initialised
+            # by the same rules; values unchanged either way, so sharded and
+            # single-device engines stay token-for-token equal).
             self.params = sh.shard_params(params, mesh, self.rules,
                                           model.template)
         self._prefill = jax.jit(self._with_mesh(model.prefill))
@@ -314,6 +310,7 @@ class Engine:
             return fn
         mesh, rules = self.mesh, self.rules
 
+        @functools.wraps(fn)      # keeps the program's name (jit_<fn>)
         def wrapped(*args, **kwargs):
             from repro.distributed.ctx import activation_policy
             with activation_policy(mesh, rules):
@@ -355,7 +352,7 @@ class Engine:
             self._unroll_source = "config"
         else:
             from repro.core.registry import GLOBAL_REGISTRY, OP_DECODE_LOOP
-            from repro.launch.mesh import mesh_axis_label
+            from repro.distributed.sharding import mesh_axis_label
             res = GLOBAL_REGISTRY.lookup_op(
                 OP_DECODE_LOOP, self.hardware, self.model.cfg.dtype,
                 (self.cfg.max_batch, self.cfg.max_len),
@@ -459,13 +456,14 @@ class Engine:
         the tuned-tile registry, and record the lookup provenance.
 
         On a mesh the traced shapes are *global*; what each shard actually
-        runs is the local GEMM — batch split over the data axes, weight dims
-        split per the sharding rules — so the registry lookup is keyed on
-        the local shape (TP therefore changes which tuned entry is hit).
-        Both shapes are recorded in the provenance.
+        runs is the local GEMM, split as ``matmul`` splits it for the
+        per-shard kernel, so the registry lookup is keyed on the local
+        shape that ``matmul`` reports (TP therefore changes which tuned
+        entry is hit).  Both shapes are recorded in the provenance.
         """
         from repro.core import capture_gemm_shapes
-        from repro.core.registry import GLOBAL_REGISTRY
+        from repro.core.registry import GLOBAL_REGISTRY, OP_GEMM
+        from repro.distributed.sharding import mesh_axis_label
         b = self.cfg.max_batch
         tok = jax.ShapeDtypeStruct((b, 1), jnp.int32)
         off = jax.ShapeDtypeStruct((), jnp.int32)
@@ -475,32 +473,24 @@ class Engine:
             cache = jax.eval_shape(
                 lambda: self.model.init_cache(b, self.cfg.max_len))
         try:
-            with capture_gemm_shapes() as shapes:
-                jax.eval_shape(self.model.decode_step, self.params, tok,
-                               cache, off, ks)
+            with capture_gemm_shapes(per_shard=True) as calls:
+                jax.eval_shape(self._with_mesh(self.model.decode_step),
+                               self.params, tok, cache, off, ks)
         except Exception:      # provenance is telemetry, never fatal
             self._tile_lookups = {}
             return
-        weight_div, batch_div = {}, 1
-        if self.mesh is not None:
-            from repro.distributed import sharding as sh
-            weight_div = sh.local_gemm_divisors(self.mesh, self.rules,
-                                                self.model.template)
-            batch_div = sh.axis_size(self.mesh, self.rules.batch_axes)
-        from repro.core.registry import OP_GEMM
-        from repro.launch.mesh import mesh_axis_label
+        # distinct weights can shard one global (K, N) differently (e.g.
+        # square wq vs wo); record a lookup per local variant
+        variants: Dict[tuple, set] = {}
+        for shape, local in calls:
+            variants.setdefault(shape, set()).add(local)
         mesh_label = mesh_axis_label(self.mesh)
-        hw = self.hardware
-        dtype = self.model.cfg.dtype
         lookups = {}
-        for (m, k, n) in sorted(set(shapes)):
-            # distinct weights can shard one global (K, N) differently
-            # (e.g. square wq vs wo); record a lookup per local variant
-            for dk, dn in weight_div.get((k, n), ((1, 1),)):
-                lm = m // batch_div if m % batch_div == 0 else m
-                lk, ln = k // dk, n // dn
-                res = GLOBAL_REGISTRY.lookup_op(OP_GEMM, hw, dtype,
-                                                (lm, lk, ln), mesh=mesh_label)
+        for (m, k, n), locals_ in sorted(variants.items()):
+            for lm, lk, ln in sorted(locals_):
+                res = GLOBAL_REGISTRY.lookup_op(
+                    OP_GEMM, self.hardware, self.model.cfg.dtype,
+                    (lm, lk, ln), mesh=mesh_label)
                 entry = {
                     "source": res.source,
                     "tile": res.config.label,
@@ -510,7 +500,7 @@ class Engine:
                 if self.mesh is not None:
                     entry["local_shape"] = f"{lm}x{lk}x{ln}"
                     entry["mesh"] = res.mesh
-                    if len(weight_div.get((k, n), ())) > 1:
+                    if len(locals_) > 1:
                         key = f"{m}x{k}x{n}->{lm}x{lk}x{ln}"
                 lookups[key] = entry
         self._tile_lookups = lookups
@@ -551,7 +541,7 @@ class Engine:
             self._page_size_source = "config"
         else:
             from repro.core.registry import GLOBAL_REGISTRY, OP_PAGED_ATTN
-            from repro.launch.mesh import mesh_axis_label
+            from repro.distributed.sharding import mesh_axis_label
             res = GLOBAL_REGISTRY.lookup_op(
                 OP_PAGED_ATTN, self.hardware, self.model.cfg.dtype,
                 (self.cfg.max_batch, self.cfg.max_len),
@@ -606,6 +596,11 @@ class Engine:
                     fixed, sh.cache_shardings(self.mesh, self.rules, fixed))
         self._pools, self._fixed = pools, fixed
         self._cur = jnp.zeros((self.cfg.max_batch,), jnp.int32)
+        if self.mesh is not None:
+            # replicated from the start, as every later admission leaves it:
+            # a first call with another layout compiles admission twice
+            self._cur = jax.device_put(self._cur,
+                                       NamedSharding(self.mesh, P()))
         if self.cfg.prefix_cache:
             from repro.serve.prefix_cache import PrefixCache
             self._prefix = PrefixCache(self._alloc)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.core import matmul
 from repro.distributed.ctx import constrain
+from repro.models.transformer import UNEMBED_AXES
 
 Z_LOSS_WEIGHT = 1e-4
 MOE_AUX_WEIGHT = 1e-2
@@ -22,8 +23,8 @@ MOE_AUX_WEIGHT = 1e-2
 
 def _ce_block(x, w, labels):
     """x: (B, C, D) final-normed hidden; w: (D, V); labels: (B, C)."""
-    logits = constrain(matmul(x, w.astype(x.dtype), out_dtype=jnp.float32),
-                       "logits")
+    logits = constrain(matmul(x, w.astype(x.dtype), out_dtype=jnp.float32,
+                              w_axes=UNEMBED_AXES), "logits")
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     ce = (lse - picked).sum()

@@ -77,6 +77,38 @@ def test_sweep_measure_mode_runs():
     assert all(p.seconds > 0 for p in res.points)
 
 
+@pytest.mark.parametrize("hardware,backend,interpret,n,d", [
+    ("cpu-interpret", "pallas-interpret", True, 32, 8),
+    ("tpu-v5e", "pallas-tpu", False, 256, 64),
+])
+def test_measure_mode_times_the_profiles_backend(monkeypatch, hardware,
+                                                 backend, interpret, n, d):
+    """Measure mode runs the profile's default backend: interpreted on
+    cpu-interpret, the compiled kernel on a TPU.  The kernels are stubbed
+    with the XLA path so the choice is observable on this host."""
+    from repro.core import FLASH_INTERPRET_SPACE, sweep_flash_attention
+    from repro.kernels import flash_attention as fa
+    from repro.kernels import ops
+    from repro.kernels.ref import attention_ref
+    seen = []
+    real_gemm = ops.gemm
+    monkeypatch.setattr(ops, "gemm", lambda a, b, *, config, backend: (
+        seen.append(backend), real_gemm(a, b, backend="xla"))[1])
+    monkeypatch.setattr(fa, "flash_attention", lambda q, k, v, *, causal,
+                        bq, bk, interpret: (seen.append(interpret),
+                                            attention_ref(q, k, v,
+                                                          causal=causal))[1])
+    cpu = hardware == "cpu-interpret"
+    sweep_gemm(n, n, n, dtype=jnp.float32, mode="measure",
+               space=INTERPRET_SPACE if cpu else None, hardware=hardware,
+               top_k=1, repeats=1, record=False)
+    sweep_flash_attention(n, n, d, dtype=jnp.float32, mode="measure",
+                          space=FLASH_INTERPRET_SPACE if cpu else None,
+                          hardware=hardware, top_k=1, repeats=1,
+                          record=False)
+    assert seen and set(seen) == {backend, interpret}
+
+
 def test_registry_persistence_roundtrip(tmp_path):
     path = os.path.join(tmp_path, "tuned.json")
     reg = TileRegistry()

@@ -332,3 +332,110 @@ def test_mesh_parity_subprocess(arch):
     assert rec["parity"], arch
     assert rec["devices"] == 8
     assert rec["axes"] == {"data": 4, "model": 2}
+
+
+_PER_SHARD = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import dataclasses, json
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs.catalog import ARCHITECTURES
+    from repro.core import execution_context, flash_attention, matmul
+    from repro.distributed.ctx import activation_policy
+    from repro.distributed.sharding import serving_rules
+    from repro.launch.mesh import build_mesh
+    from repro.models import build_model
+    from repro.models.layers import COL, ROW
+    from repro.serve import Engine, ServeConfig
+
+    mesh = build_mesh("data=2,model=2")
+    rules = serving_rules(mesh)
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    x = jax.random.normal(ks[0], (4, 6, 64))
+    w_col = jax.random.normal(ks[1], (64, 96)) / 8
+    w_row = jax.random.normal(ks[2], (96, 64)) / 8
+    bias = jax.random.normal(ks[3], (64,))
+    q = jax.random.normal(ks[4], (4, 24, 4, 16))
+    kv = jax.random.normal(ks[5], (4, 24, 2, 16))
+    kv_start = jnp.asarray([0, 3, 7, 1], jnp.int32)
+
+    def model_bits(x, w_col, w_row, bias, q, kv, kv_start):
+        h = matmul(x, w_col, activation="silu", w_axes=COL)
+        y = matmul(h, w_row, bias=bias, activation="gelu", w_axes=ROW)
+        a = flash_attention(q, kv, kv, causal=True, kv_start=kv_start,
+                            bq=8, bk=8)
+        return y, a
+
+    args = (x, w_col, w_row, bias, q, kv, kv_start)
+    with execution_context(backend="xla"):
+        want = jax.jit(model_bits)(*args)
+
+    def sharded(*a):
+        with activation_policy(mesh, rules):
+            return model_bits(*a)
+
+    with execution_context(backend="pallas-interpret",
+                           hardware="cpu-interpret"):
+        fn = jax.jit(sharded)
+        got = fn(*args)
+        hlo = fn.lower(*args).as_text()
+    err = max(float(jnp.abs(g - w).max()) for g, w in zip(got, want))
+
+    cfg = dataclasses.replace(ARCHITECTURES["llama3.2-1b"].reduced(),
+                              attention_impl="flash")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    prompts = [[5, 9, 2], [7, 1, 4, 4, 2, 8, 3], [1]]
+    with execution_context(backend="pallas-interpret"):
+        one = Engine(model, params, ServeConfig(max_batch=4, max_len=32))
+        many = Engine(model, params, ServeConfig(max_batch=4, max_len=32,
+                                                 mesh="data=1,model=4"))
+        out1, out4 = one.generate(prompts, 4), many.generate(prompts, 4)
+    print("RESULT " + json.dumps({
+        "err": err, "shard_map": hlo.count("sdy.manual_computation"),
+        "parity": out1 == out4,
+        "local": many.stats()["decode_tile_lookups"]}))
+""")
+
+
+def test_pallas_kernels_run_per_shard_on_a_mesh():
+    """On a mesh the Pallas GEMM (column and row parallel, fused epilogues)
+    and flash attention run under shard_map on per-shard operands, agree
+    with the XLA path, and a TP engine serves the single-device tokens."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _PER_SHARD],
+                          capture_output=True, text=True, env=env,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][-1]
+    rec = json.loads(line[len("RESULT "):])
+    assert rec["err"] < 1e-4, rec
+    assert rec["shard_map"] >= 3, rec        # two GEMMs + one flash call
+    assert rec["parity"], rec
+    assert all("local_shape" in v for v in rec["local"].values()), rec
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+def test_matmul_on_a_mesh_requires_w_axes(backend):
+    """Under a mesh policy every matmul names its weight's logical axes:
+    without them a sharded weight would be gathered whole into every
+    shard.  The check holds on every backend, so the CPU mesh tests cover
+    the call sites the per-shard TPU kernel runs."""
+    from repro.core import capture_gemm_shapes, execution_context, matmul
+    from repro.distributed.ctx import activation_policy
+    from repro.distributed.sharding import serving_rules
+    from repro.models.layers import COL
+    mesh = build_mesh("data=1,model=1")
+    x, w = jnp.ones((2, 3, 16)), jnp.ones((16, 8))
+    with execution_context(backend=backend), \
+            activation_policy(mesh, serving_rules(mesh)):
+        with pytest.raises(ValueError, match="w_axes"):
+            matmul(x, w)
+        with capture_gemm_shapes(per_shard=True) as calls:
+            y = matmul(x, w, w_axes=COL)
+    assert y.shape == (2, 3, 8)
+    assert calls == [((6, 16, 8), (6, 16, 8))]
+    assert matmul(x, w).shape == (2, 3, 8)     # no mesh: no axes needed

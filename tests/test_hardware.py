@@ -25,8 +25,9 @@ SRC = os.path.join(REPO, "src")
 
 
 class _FakeDev:
-    def __init__(self, platform):
+    def __init__(self, platform, device_kind="cpu"):
         self.platform = platform
+        self.device_kind = device_kind
 
 
 # ---------------------------------------------------------------------------
@@ -42,7 +43,20 @@ def test_cpu_only_devices_detect_cpu_interpret(monkeypatch):
     assert hw.detect_hardware([_FakeDev("cpu")]) == CPU_INTERPRET.name
     assert hw.detect_hardware([_FakeDev("cpu"), _FakeDev("gpu")]) == \
         GPU_GENERIC.name
-    assert hw.detect_hardware([_FakeDev("tpu")]) == TPU_V5E.name
+    assert hw.detect_hardware([_FakeDev("tpu", "TPU v5 lite")]) == \
+        TPU_V5E.name
+
+
+def test_unknown_tpu_kind_is_an_error(monkeypatch):
+    """A TPU is keyed on its device_kind: an unknown kind fails loudly
+    instead of serving with another chip's peaks and tiles."""
+    monkeypatch.delenv(hw.HARDWARE_ENV, raising=False)
+    with pytest.raises(RuntimeError, match="unknown TPU device_kind"):
+        hw.detect_hardware([_FakeDev("tpu", "TPU v9 imaginary")])
+    # the env pin still names the profile explicitly
+    monkeypatch.setenv(hw.HARDWARE_ENV, TPU_V5E.name)
+    assert hw.detect_hardware([_FakeDev("tpu", "TPU v9 imaginary")]) == \
+        TPU_V5E.name
 
 
 def test_env_pin_beats_detection(monkeypatch):

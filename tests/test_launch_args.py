@@ -107,3 +107,38 @@ def test_train_legacy_mesh_pair_builds_a_mesh_spec():
     if {"mesh_data", "mesh_model"} & used and not args.mesh:
         args.mesh = f"data={args.mesh_data or 1},model={args.mesh_model or 1}"
     assert args.mesh == "data=4,model=2"
+
+
+@pytest.mark.parametrize("hardware,env", [
+    ("tpu-v5e", "LIBTPU_INIT_ARGS"),   # XLA_FLAGS aborts on xla_tpu_* flags
+    ("gpu-generic", "XLA_FLAGS"),
+])
+def test_latency_hiding_flags_reach_the_runtime(monkeypatch, hardware, env):
+    """A mesh launcher named a profile sets its collective-overlap flags
+    where that runtime reads them, keeps a flag the caller set, and adds
+    nothing twice."""
+    from repro.core.hardware import get_profile
+    from repro.launch.common import apply_latency_hiding_flags
+    flags = get_profile(hardware).xla_latency_flags
+    assert flags
+    other = {"LIBTPU_INIT_ARGS", "XLA_FLAGS"} - {env}
+    monkeypatch.delenv("REPRO_HARDWARE", raising=False)
+    for name in other:
+        monkeypatch.delenv(name, raising=False)
+    user = flags[0].split("=")[0] + "=false"
+    monkeypatch.setenv(env, user)
+    assert apply_latency_hiding_flags(hardware) == list(flags[1:])
+    import os
+    assert os.environ[env].split() == [user] + list(flags[1:])
+    assert not any(os.environ.get(name) for name in other)
+    assert apply_latency_hiding_flags(hardware) == []
+    # $REPRO_HARDWARE names the profile too; no name and the host CPU set
+    # nothing (detecting the profile would start the backend)
+    monkeypatch.delenv(env)
+    monkeypatch.setenv("REPRO_HARDWARE", hardware)
+    assert apply_latency_hiding_flags(None) == list(flags)
+    monkeypatch.delenv(env)
+    monkeypatch.delenv("REPRO_HARDWARE")
+    assert apply_latency_hiding_flags(None) == []
+    assert apply_latency_hiding_flags("cpu-interpret") == []
+    assert env not in os.environ

@@ -198,3 +198,34 @@ def test_fused_decode_one_device_get_per_wave_under_tracing(
     ann = summarize_events(s.events())["annotations"]
     assert ann.get("serve.prefill_wave", {}).get("count") == waves
     assert ann.get("serve.decode_wave", {}).get("count") == waves
+
+
+# ---------------------------------------------------------------------------
+# scripts/profile.py end to end on a mesh
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("cmd,extra", [
+    ("serve", ["--plen", "4", "--max-new", "2", "--max-len", "32"]),
+    ("train", ["--steps", "1", "--seq-len", "8"]),
+])
+def test_profile_script_on_a_mesh(tmp_path, cmd, extra):
+    """The profiler's serve and train commands build their --mesh (forcing
+    the host device count themselves), capture a trace and write a valid
+    PROFILE with the roofline of the same step."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    out = tmp_path / "PROFILE.json"
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "scripts", "profile.py"), cmd,
+         "--mesh", "data=2,model=2", "--batch", "4", "--out", str(out),
+         "--trace-dir", str(tmp_path / "trace")] + extra,
+        capture_output=True, text=True, env=env, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    with open(out) as f:
+        blob = validate_profile(json.load(f))
+    assert blob["mesh"] == "data2xmodel2"
+    assert blob["roofline"] and blob["roofline"]["chips"] == 4, proc.stdout

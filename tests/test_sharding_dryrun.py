@@ -103,30 +103,34 @@ def test_rules_for_mesh_spec_edge_cases():
     assert tuple(sp) == ("model", None)
 
 
-def test_local_gemm_divisors():
-    """The serve engine's local-shape lookups: weight (K, N) dims map to the
-    mesh-axis sizes their sharding spec divides them by."""
-    from repro.distributed.sharding import local_gemm_divisors, rules_for_mesh
+def test_gemm_shard_layout():
+    """The per-shard GEMM split matmul runs on a mesh — and reports to the
+    serve engine's tile lookups — from the weight's logical axes: TP splits
+    kept, FSDP shards gathered, non-divisible dims replicated."""
+    from repro.core.gemm_api import _shard_layout
+    from repro.distributed.ctx import ActivationPolicy
+    from repro.distributed.sharding import mesh_axis_label, rules_for_mesh
 
     mesh = _FakeMesh(data=4, model=2)
-    rules = rules_for_mesh(mesh)
-    template = {
-        "wq": ParamSpec((64, 128), ("embed", "ff")),       # (data, model)
-        "embed": ParamSpec((256, 64), ("vocab", "embed")),  # (model, data)
-        "stack": ParamSpec((4, 64, 128), ("layer", "embed", "ff")),
-        "norm": ParamSpec((64,), ("embed",)),               # 1-D: skipped
-        "odd": ParamSpec((63, 125), ("vocab", "embed")),    # non-divisible
-        # square projections: same global (K, N), different axis order —
-        # BOTH divisor variants must be surfaced, not first-leaf-wins
-        "sq_in": ParamSpec((64, 64), ("embed", "ff")),
-        "sq_out": ParamSpec((64, 64), ("ff", "embed")),
-    }
-    div = local_gemm_divisors(mesh, rules, template)
-    assert div[(64, 128)] == ((4, 2),)    # K split by FSDP, N by TP
-    assert div[(256, 64)] == ((2, 4),)
-    assert div[(63, 125)] == ((1, 1),)    # non-divisible -> replicated -> 1
-    assert div[(64, 64)] == ((2, 4), (4, 2))   # wq-like AND wo-like variants
-    assert (64,) not in div
+    fsdp = ActivationPolicy(mesh, rules_for_mesh(mesh))
+    col, row = ("embed", "ff"), ("ff", "embed")
+    # column parallel: N on the model axis; the FSDP split of K is gathered
+    assert _shard_layout(fsdp, 8, 64, 128, col) == (
+        (("data",), None, "model"), (2, 64, 64))
+    # row parallel: K on the model axis (partial sums all-reduced)
+    assert _shard_layout(fsdp, 8, 128, 64, row) == (
+        (("data",), "model", None), (2, 64, 64))
+    # a square (K, N) splits both ways, by its axes, not its shape
+    assert _shard_layout(fsdp, 8, 64, 64, col)[1] == (2, 64, 32)
+    assert _shard_layout(fsdp, 8, 64, 64, row)[1] == (2, 32, 64)
+    # M not divisible by the batch axes, N not by the model axis: replicated
+    assert _shard_layout(fsdp, 3, 64, 125, col) == (
+        (None, None, None), (3, 64, 125))
+    assert _shard_layout(fsdp, 8, 64, 128, (None, None))[1] == (2, 64, 128)
+    with pytest.raises(ValueError, match="w_axes"):
+        _shard_layout(fsdp, 8, 64, 128, None)
+    assert mesh_axis_label(mesh) == "data4xmodel2"
+    assert mesh_axis_label(None) is None
 
 
 _SUBPROC = textwrap.dedent("""
