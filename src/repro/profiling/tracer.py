@@ -7,11 +7,15 @@ created, no XLA/env state touched, no profiler hooks installed — so it can
 stay permanently in the serve/train launchers at zero cost.
 
 ``annotate(name)`` is the marker the engine and trainer thread through
-their hot paths.  It stacks ``jax.profiler.TraceAnnotation`` (a host-side
-timeline event, how the breakdown attributes wall time to e.g.
-``serve.decode_wave``) with ``jax.named_scope`` (an HLO metadata scope, so
-compiled-op names carry the region they were traced under).  Both are
-near-free when no trace is active, so annotations are unconditional.
+their hot paths: a host span (``jax.profiler.TraceAnnotation``) on the
+profiler's timeline, on the same clock as the device ops, which is how a
+breakdown attributes wall time to e.g. ``serve.chunk``.  It is a host span
+only: every call site wraps a call to an already-jitted program, where a
+``jax.named_scope`` would reach no HLO.  Code that wants an HLO scope
+inside a traced function writes ``jax.named_scope`` there.  A span costs
+one context manager when no trace is active, so annotations are
+unconditional; their args (ints, shown as strings in the capture) are
+built only while :func:`recording` is true.
 """
 from __future__ import annotations
 
@@ -71,8 +75,16 @@ def trace(out_dir: Optional[str] = None, *,
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Mark a code region in the trace timeline AND the HLO metadata."""
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
-        yield
+def annotate(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span named ``name`` in the trace timeline, with ``args``.
+
+    Used as ``with annotate("serve.chunk") as span:``; a span whose args
+    are known only at its end sets them with ``span.set_metadata(...)``.
+    """
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def recording() -> bool:
+    """True while a capture records host spans: the test a call site makes
+    before it builds a span's args."""
+    return jax.profiler.TraceAnnotation.is_enabled()

@@ -87,7 +87,8 @@ class GenerationResult:
 
     ``tokens`` matches the legacy raw-token return exactly (the EOS token,
     when hit, is included).  ``ttft_s`` is submit-to-first-token-host-
-    visible; ``tok_per_s`` is ``len(tokens) / total_s``.  ``prefix_hit`` is
+    visible (through a ``Server``, from ``Server.submit``); ``tok_per_s``
+    is ``len(tokens) / total_s``.  ``prefix_hit`` is
     ``"full"`` / ``"partial"`` / ``None`` with ``cached_prefix_tokens``
     counting the prompt tokens served from the prefix cache.
     """

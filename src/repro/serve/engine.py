@@ -166,7 +166,11 @@ class _Request:
     stream: Optional[Callable[[api.StreamEvent], None]] = None
     result: Optional[api.GenerationResult] = None
     finish_reason: Optional[str] = None
+    # Stamps on the engine's clock (time.perf_counter): the caller's submit
+    # (Server.submit, else Engine.submit), the engine's ingest, admission.
     t_submit: float = 0.0
+    t_ingest: float = 0.0
+    t_admit: Optional[float] = None
     t_first: Optional[float] = None   # first token host-visible (TTFT end)
     prefix_hit: Optional[str] = None  # "full" | "partial" | None
     cached_prefix_tokens: int = 0
@@ -288,9 +292,10 @@ class Engine:
         self._copy_fn = None              # jitted COW page copy
         self._prefix = None               # PrefixCache (continuous only)
         # Server-mode ingestion: a callable polled at every chunk/wave
-        # boundary yielding (api.Request, RequestHandle) pairs submitted
-        # mid-drain (see repro.serve.server.Server).
+        # boundary yielding (api.Request, RequestHandle, t_submit) triples
+        # submitted mid-drain (see repro.serve.server.Server).
         self._ingest_hook: Optional[Callable] = None
+        self._admit_passes = 0            # admission passes (admit_id)
         self._lat_ttft: List[float] = []  # finished-request TTFT records
         self._lat_tok: List[float] = []   # finished-request tok/s records
         self._stats: Dict[str, float] = {
@@ -829,7 +834,8 @@ class Engine:
     # -- request queue --------------------------------------------------
     def submit(self, request, max_new_tokens: Optional[int] = None,
                row: Optional[int] = None,
-               _handle: Optional[api.RequestHandle] = None):
+               _handle: Optional[api.RequestHandle] = None,
+               _t_submit: Optional[float] = None):
         """Queue one generation request.
 
         The typed surface takes an :class:`repro.serve.api.Request` and
@@ -845,8 +851,9 @@ class Engine:
         legacy ``{rid: tokens}`` dict) behind one ``DeprecationWarning``
         per process; see ``docs/SERVING.md`` for migration notes.
 
-        ``_handle`` is internal (server mode pre-creates the handle on the
-        ingestion thread).
+        ``_handle`` and ``_t_submit`` are internal: server mode creates the
+        handle and stamps the submit time on the caller's thread, so
+        ``ttft_s``/``total_s`` count the wait before ingest.
         """
         global _LEGACY_SUBMIT_WARNED
         if isinstance(request, api.Request):
@@ -902,8 +909,10 @@ class Engine:
                 f"max_len ({self.cfg.max_len})")
         rid = self._next_rid
         self._next_rid += 1
+        now = time.perf_counter()
         req = _Request(rid, prompt, max_new, row, legacy=legacy,
-                       stream=stream, t_submit=time.perf_counter())
+                       stream=stream, t_ingest=now,
+                       t_submit=now if _t_submit is None else _t_submit)
         if not legacy:
             handle = _handle if _handle is not None else api.RequestHandle()
             handle.request_id = rid
@@ -964,8 +973,13 @@ class Engine:
         without an ingest hook)."""
         if self._ingest_hook is None:
             return
-        for req, handle in self._ingest_hook():
-            self.submit(req, _handle=handle)
+        from repro.profiling import annotate, recording
+        with annotate("serve.ingest") as span:
+            items = self._ingest_hook()
+            for req, handle, t_submit in items:
+                self.submit(req, _handle=handle, _t_submit=t_submit)
+            if recording():
+                span.set_metadata(n=len(items))
 
     def _finish_request(self, req: _Request, reason: str,
                         now: float) -> None:
@@ -1030,15 +1044,21 @@ class Engine:
         FRONT with a clean restart), run one fused decode chunk, stream its
         tokens, then evict rows that finished inside it.  Exactly one host
         transfer per chunk.  Returns the finished requests.
+
+        Each phase of a pass is a flat host span (``serve.ingest``,
+        ``serve.admit.plan``, ``serve.prefix_restore``, ``serve.admit``,
+        ``serve.prefix_insert``, ``serve.chunk.plan`` twice: pages, then
+        the chunk's indices, ``serve.chunk``, ``serve.chunk.wait``,
+        ``serve.emit``; docs/PROFILING.md).
         """
         if extra_inputs and any(r.row is None for r in self._queue):
             raise ValueError(
                 "extra_inputs needs every request submitted with row= "
                 "(its index into the extra arrays); generate() does this")
+        from repro.profiling import annotate
         self._ensure_pool()
         finished: List[_Request] = []
         active: Dict[int, _Request] = {}        # slot -> request
-        eos = self.cfg.eos_token
         try:
             while True:
                 self._poll_ingest()
@@ -1046,48 +1066,25 @@ class Engine:
                     break
                 if self._queue:
                     key = self._admit_batch(active, extra_inputs, key)
-                preempted = self._csched.ensure_chunk_pages(self._chunk)
-                # Requeue victims at the queue front, smallest rid first,
-                # with generated tokens discarded: re-admission restarts
-                # them cleanly (greedy decode makes the restart exact).
-                for row in sorted(preempted, key=lambda r: r.rid,
-                                  reverse=True):
-                    req = active.pop(row.slot)
-                    self._sched.evict(req)
-                    req.tokens = None
-                    req.t_first = None
-                    req.prefix_hit = None
-                    req.cached_prefix_tokens = 0
-                    self._queue.insert(0, req)
+                with annotate("serve.chunk.plan"):
+                    preempted = self._csched.ensure_chunk_pages(self._chunk)
+                    # Requeue victims at the queue front, smallest rid
+                    # first, with generated tokens discarded: re-admission
+                    # restarts them cleanly (greedy decode makes the restart
+                    # exact).
+                    for row in sorted(preempted, key=lambda r: r.rid,
+                                      reverse=True):
+                        req = active.pop(row.slot)
+                        self._sched.evict(req)
+                        req.tokens = None
+                        req.t_first = None
+                        req.prefix_hit = None
+                        req.cached_prefix_tokens = 0
+                        self._queue.insert(0, req)
                 if not active:
                     continue        # preemption freed the pool; re-admit
                 key, buf_h, lens_h = self._run_chunk(key)
-                now = time.perf_counter()
-                for slot in list(active):
-                    req = active[slot]
-                    row = self._csched.rows[slot]
-                    n = int(lens_h[slot])
-                    emitted = [int(t) for t in buf_h[slot, :n]]
-                    base = len(req.tokens)
-                    req.tokens.extend(emitted)
-                    if emitted and req.t_first is None:
-                        req.t_first = now
-                    if req.stream is not None:
-                        for j, t in enumerate(emitted):
-                            req.stream(api.StreamEvent(req.rid, t, base + j))
-                    self._stats["tokens_generated"] += n
-                    row.length += n
-                    row.budget_left -= n
-                    if row.budget_left <= 0 or (eos is not None
-                                                and eos in emitted):
-                        reason = (api.FINISH_STOP
-                                  if eos is not None and eos in emitted
-                                  else api.FINISH_LENGTH)
-                        self._csched.evict(row)
-                        self._sched.evict(req)
-                        del active[slot]
-                        self._finish_request(req, reason, now)
-                        finished.append(req)
+                self._emit(active, buf_h, lens_h, finished)
         except Exception as exc:
             # Free every live row (pages AND slots) so one bad request
             # can't brick the pool for the next call; fail their handles
@@ -1124,49 +1121,123 @@ class Engine:
           for exactness but redirect shared-column writes to TRASH;
         * every prefilled prompt (cache enabled, no extras) is inserted
           back into the cache while its pages are known-live.
+
+        While a capture records, each admitted request leaves one
+        ``serve.request`` event (its waits before admission) inside
+        ``serve.admit.plan``.
         """
+        from repro.profiling import annotate, recording
+        tracing = recording()
+        admit_id = self._admit_passes
         admitted: List[_Request] = []
         hits = []                       # (req, RowState, cache entry)
         caching = self._prefix is not None and not extra_inputs
-        while self._queue:
-            nxt = self._queue[0]
-            m = self._prefix.match(nxt.prompt) if caching else None
-            shared = list(m.pages) if m is not None else []
-            if not self._csched.can_admit(len(nxt.prompt),
-                                          shared_pages=len(shared)):
-                # only sacrifice cached pages for a PAGE shortage — a busy
-                # slot frees itself at the next chunk boundary, and evicting
-                # for it would churn the cache to no benefit
-                if (self._csched.free_slots > 0
-                        and self._prefix is not None
-                        and self._prefix.evict_one()):
-                    continue
-                break
-            req = self._queue.pop(0)
-            row = self._csched.admit(req.rid, len(req.prompt), req.max_new,
-                                     shared_pages=shared)
-            self._sched.admit(req)      # lockstep: same smallest-free slot
-            assert req.slot == row.slot
-            req.tokens = []
-            active[row.slot] = req
-            if caching:
-                self._prefix.record_admit(m, len(req.prompt))
-            if m is not None:
-                req.prefix_hit = (api.PREFIX_HIT_FULL if m.full
-                                  else api.PREFIX_HIT_PARTIAL)
-                req.cached_prefix_tokens = m.tokens
-            if m is not None and m.full:
-                hits.append((req, row, m.entry))
-            else:
-                admitted.append(req)
+        with annotate("serve.admit.plan"):
+            while self._queue:
+                nxt = self._queue[0]
+                m = self._prefix.match(nxt.prompt) if caching else None
+                shared = list(m.pages) if m is not None else []
+                if not self._csched.can_admit(len(nxt.prompt),
+                                              shared_pages=len(shared)):
+                    # only sacrifice cached pages for a PAGE shortage — a
+                    # busy slot frees itself at the next chunk boundary, and
+                    # evicting for it would churn the cache to no benefit
+                    if (self._csched.free_slots > 0
+                            and self._prefix is not None
+                            and self._prefix.evict_one()):
+                        continue
+                    break
+                req = self._queue.pop(0)
+                req.t_admit = time.perf_counter()
+                row = self._csched.admit(req.rid, len(req.prompt),
+                                         req.max_new, shared_pages=shared)
+                self._sched.admit(req)  # lockstep: same smallest-free slot
+                assert req.slot == row.slot
+                req.tokens = []
+                active[row.slot] = req
+                if caching:
+                    self._prefix.record_admit(m, len(req.prompt))
+                if m is not None:
+                    req.prefix_hit = (api.PREFIX_HIT_FULL if m.full
+                                      else api.PREFIX_HIT_PARTIAL)
+                    req.cached_prefix_tokens = m.tokens
+                if m is not None and m.full:
+                    hits.append((req, row, m.entry))
+                else:
+                    admitted.append(req)
+                if tracing:
+                    with annotate(
+                            "serve.request", rid=req.rid, admit_id=admit_id,
+                            front_us=round((req.t_ingest - req.t_submit)
+                                           * 1e6),
+                            queue_us=round((req.t_admit - req.t_ingest)
+                                           * 1e6),
+                            hit=0 if m is None else 2 if m.full else 1):
+                        pass
+            if admitted:
+                plen, toks, kv_start, dest, slot_map = self._plan_admission(
+                    admitted)
+        if admitted or hits:
+            self._admit_passes += 1
         if hits:
             key = self._restore_hits(hits, key)
         if not admitted:
             return key
 
-        from repro.serve.kv_pages import TRASH_PAGE
         cfg = self.cfg
         b = cfg.max_batch
+        if self._admit_fn is None:
+            self._admit_fn = self._build_admit_fn()
+        t0 = time.perf_counter()
+        with annotate("serve.admit") as span:
+            if tracing:
+                span.set_metadata(
+                    admit_id=admit_id, rows=len(admitted), batch=b,
+                    bucket=plen,
+                    prompt_tokens=sum(len(r.prompt) for r in admitted),
+                    cached_tokens=sum(
+                        r.cached_prefix_tokens for r in admitted
+                        if r.prefix_hit == api.PREFIX_HIT_PARTIAL))
+            batch = {"tokens": jnp.asarray(toks),
+                     "kv_start": jnp.asarray(kv_start)}
+            if extra_inputs:
+                rows = [r.row for r in admitted]
+                slots = [r.slot for r in admitted]
+                for name, arr in extra_inputs.items():
+                    padded = jnp.zeros((b,) + arr.shape[1:], arr.dtype)
+                    batch[name] = padded.at[jnp.asarray(slots)].set(
+                        jnp.asarray(arr)[jnp.asarray(rows)])
+            batch = self._place_batch(batch)
+            scratch = self._scratch_cache(plen)
+            self._record_prefill_flash_tiles(plen)
+            self._plen_buckets.add(int(plen))
+            (self._pools, self._fixed, self._cur, key,
+             logits0) = self._admit_fn(
+                self.params, batch, scratch, self._pools, self._fixed,
+                self._cur, key, jnp.asarray(dest), jnp.asarray(slot_map))
+            if cfg.profile:
+                # deliberate sync: profile mode wants the true prefill /
+                # decode wall-time split, not dispatch-pipeline overlap
+                jax.block_until_ready(self._cur)   # analysis: allow(TP001)
+        self._stats["prefill_seconds"] += time.perf_counter() - t0
+        self._stats["admission_prefills"] += 1
+        if caching:
+            # Insert while the rows' pages are known-live: the cache takes
+            # its own refs, so the entries outlive the rows.
+            with annotate("serve.prefix_insert"):
+                for r in admitted:
+                    row = self._csched.rows[r.slot]
+                    self._prefix.insert(r.prompt, row.pages,
+                                        logits0[r.slot],
+                                        self._slice_fixed_row(r.slot))
+        return key
+
+    def _plan_admission(self, admitted: List[_Request]):
+        """Host inputs of one batched admission prefill: the bucket
+        ``plen``, right-aligned tokens, per-row ``kv_start``, each
+        column's KV destination in the pool, and the slot map."""
+        from repro.serve.kv_pages import TRASH_PAGE
+        b = self.cfg.max_batch
         page = self._page_size
         plen = _bucket_len(max(len(r.prompt) for r in admitted))
         toks = np.zeros((b, plen), np.int32)
@@ -1195,84 +1266,95 @@ class Engine:
         # scatter, so non-admitted slots keep their live state untouched.
         slot_map = np.full((b,), b, np.int32)
         slot_map[:len(admitted)] = [r.slot for r in admitted]
-
-        batch = {"tokens": jnp.asarray(toks),
-                 "kv_start": jnp.asarray(kv_start)}
-        if extra_inputs:
-            rows = [r.row for r in admitted]
-            slots = [r.slot for r in admitted]
-            for name, arr in extra_inputs.items():
-                padded = jnp.zeros((b,) + arr.shape[1:], arr.dtype)
-                batch[name] = padded.at[jnp.asarray(slots)].set(
-                    jnp.asarray(arr)[jnp.asarray(rows)])
-        batch = self._place_batch(batch)
-        scratch = self._scratch_cache(plen)
-        self._record_prefill_flash_tiles(plen)
-        self._plen_buckets.add(int(plen))
-        if self._admit_fn is None:
-            self._admit_fn = self._build_admit_fn()
-        from repro.profiling import annotate
-        t0 = time.perf_counter()
-        with annotate("serve.prefill_admit"):
-            (self._pools, self._fixed, self._cur, key,
-             logits0) = self._admit_fn(
-                self.params, batch, scratch, self._pools, self._fixed,
-                self._cur, key, jnp.asarray(dest), jnp.asarray(slot_map))
-            if cfg.profile:
-                # deliberate sync: profile mode wants the true prefill /
-                # decode wall-time split, not dispatch-pipeline overlap
-                jax.block_until_ready(self._cur)   # analysis: allow(TP001)
-        self._stats["prefill_seconds"] += time.perf_counter() - t0
-        self._stats["admission_prefills"] += 1
-        if caching:
-            # Insert while the rows' pages are known-live: the cache takes
-            # its own refs, so the entries outlive the rows.
-            for r in admitted:
-                row = self._csched.rows[r.slot]
-                self._prefix.insert(r.prompt, row.pages, logits0[r.slot],
-                                    self._slice_fixed_row(r.slot))
-        return key
+        return plen, toks, kv_start, dest, slot_map
 
     def _run_chunk(self, key: jax.Array):
         """One fused decode chunk over every live row; returns the updated
         key plus the host copies of the chunk's token buffer and counts
         (the chunk's single device transfer)."""
+        from repro.profiling import annotate, recording
         from repro.serve.kv_pages import gather_indices, scatter_indices
-        rows = self._csched.rows
-        b = self.cfg.max_batch
-        chunk = self._chunk
-        page = self._page_size
-        width = _bucket_len(max(r.length for r in rows.values()) + chunk)
-        gidx = gather_indices(rows, b, width, chunk, page)
-        sidx = scatter_indices(rows, b, chunk, page)
-        kv_start = np.full((b,), width - chunk, np.int32)
-        budget = np.zeros((b,), np.int32)
-        for slot, row in rows.items():
-            kv_start[slot] = width - chunk - row.length
-            budget[slot] = row.budget_left
+        with annotate("serve.chunk.plan"):
+            rows = self._csched.rows
+            b = self.cfg.max_batch
+            chunk = self._chunk
+            page = self._page_size
+            width = _bucket_len(max(r.length for r in rows.values()) + chunk)
+            gidx = gather_indices(rows, b, width, chunk, page)
+            sidx = scatter_indices(rows, b, chunk, page)
+            kv_start = np.full((b,), width - chunk, np.int32)
+            budget = np.zeros((b,), np.int32)
+            for slot, row in rows.items():
+                kv_start[slot] = width - chunk - row.length
+                budget[slot] = row.budget_left
+            # The fused loop advances in ``unroll``-token strides; clamp to
+            # a divisor of the chunk so the final stride can't overshoot the
+            # token buffer (a clamped dynamic_update_slice would silently
+            # rewrite the last column).
+            unroll = min(self._resolve_unroll(), chunk)
+            while chunk % unroll:
+                unroll -= 1
         if self._chunk_fn is None:
             self._chunk_fn = self._build_chunk_fn()
-        # The fused loop advances in ``unroll``-token strides; clamp to a
-        # divisor of the chunk so the final stride can't overshoot the
-        # token buffer (a clamped dynamic_update_slice would silently
-        # rewrite the last column).
-        unroll = min(self._resolve_unroll(), chunk)
-        while chunk % unroll:
-            unroll -= 1
-        from repro.profiling import annotate
+        tracing = recording()
+        chunk_id = self._stats["chunks"]
         t0 = time.perf_counter()
-        with annotate("serve.decode_chunk"):
+        with annotate("serve.chunk") as span:
+            if tracing:
+                span.set_metadata(chunk_id=chunk_id, rows=len(rows),
+                                  width=width)
             (self._pools, self._fixed, self._cur, key, buf,
              lens) = self._chunk_fn(
                 self.params, self._pools, self._fixed, self._cur, key,
                 jnp.asarray(gidx), jnp.asarray(sidx), jnp.asarray(kv_start),
                 jnp.asarray(budget), width=width, chunk=chunk, unroll=unroll)
+        with annotate("serve.chunk.wait") as span:
+            if tracing:
+                span.set_metadata(chunk_id=chunk_id)
             # The ONE host transfer of this chunk.
             buf_h, lens_h = jax.device_get((buf, lens))  # analysis: allow(TP001)
         self._stats["decode_seconds"] += time.perf_counter() - t0
         self._stats["device_transfers"] += 1
         self._stats["chunks"] += 1
         return key, buf_h, lens_h
+
+    def _emit(self, active: Dict[int, _Request], buf_h, lens_h,
+              finished: List[_Request]) -> None:
+        """Hand one chunk's tokens to their rows and stream callbacks, then
+        evict and finish the rows that ended inside it."""
+        from repro.profiling import annotate, recording
+        eos = self.cfg.eos_token
+        emitted_total = 0
+        with annotate("serve.emit") as span:
+            now = time.perf_counter()
+            for slot in list(active):
+                req = active[slot]
+                row = self._csched.rows[slot]
+                n = int(lens_h[slot])
+                emitted = [int(t) for t in buf_h[slot, :n]]
+                emitted_total += n
+                base = len(req.tokens)
+                req.tokens.extend(emitted)
+                if emitted and req.t_first is None:
+                    req.t_first = now
+                if req.stream is not None:
+                    for j, t in enumerate(emitted):
+                        req.stream(api.StreamEvent(req.rid, t, base + j))
+                self._stats["tokens_generated"] += n
+                row.length += n
+                row.budget_left -= n
+                if row.budget_left <= 0 or (eos is not None
+                                            and eos in emitted):
+                    reason = (api.FINISH_STOP
+                              if eos is not None and eos in emitted
+                              else api.FINISH_LENGTH)
+                    self._csched.evict(row)
+                    self._sched.evict(req)
+                    del active[slot]
+                    self._finish_request(req, reason, now)
+                    finished.append(req)
+            if recording():
+                span.set_metadata(tokens=emitted_total)
 
     # -- batched generation ---------------------------------------------
     def generate(self, prompts: List[List[int]], max_new_tokens: int,

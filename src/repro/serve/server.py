@@ -13,8 +13,11 @@ to work continuously (MaxText's ``OfflineInference``/``JetThread`` shape):
   live batch without waiting for it to finish — true continuous ingestion,
   not run-to-completion batching;
 * per-token ``stream`` callbacks and handle resolution happen on the
-  worker thread the moment tokens/results are host-visible, so TTFT in
-  ``stats()["latency"]`` measures the real submit-to-first-token path.
+  worker thread the moment tokens/results are host-visible, and
+  ``submit`` stamps each request on the caller's thread, so TTFT in
+  ``stats()["latency"]`` and ``GenerationResult.ttft_s`` measure the real
+  submit-to-first-token path, the wait before the worker ingests it
+  included.
 
 Requests served through a Server cannot use ``extra_inputs``-style shared
 arrays (``Request.row`` must be None): extras are positional per drain,
@@ -31,9 +34,14 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 from repro.serve import api
+
+#: one submitted request: (request, handle, submit time on the engine's
+#: clock, ``time.perf_counter``)
+_Item = Tuple[api.Request, api.RequestHandle, float]
 
 
 class Server:
@@ -49,8 +57,7 @@ class Server:
     def __init__(self, engine, poll_timeout_s: float = 0.05):
         self.engine = engine
         self.poll_timeout_s = float(poll_timeout_s)
-        self._ingest: "queue.Queue[Tuple[api.Request, api.RequestHandle]]" \
-            = queue.Queue()
+        self._ingest: "queue.Queue[_Item]" = queue.Queue()
         self._stop = threading.Event()
         self._worker: Optional[threading.Thread] = None
         self._lock = threading.Lock()
@@ -106,11 +113,11 @@ class Server:
         handle = api.RequestHandle()
         with self._lock:
             self._submitted += 1
-        self._ingest.put((request, handle))
+        self._ingest.put((request, handle, time.perf_counter()))
         return handle
 
     # -- worker thread ---------------------------------------------------
-    def _poll_ingest(self) -> List[Tuple[api.Request, api.RequestHandle]]:
+    def _poll_ingest(self) -> List[_Item]:
         """Engine callback at each chunk/wave boundary: everything queued
         since the last boundary joins the live batch."""
         items = []
@@ -144,7 +151,7 @@ class Server:
                 self._served += len(results)
 
     def _drop_pending(self, exc: BaseException) -> None:
-        for _, handle in self._poll_ingest():
+        for _, handle, _ in self._poll_ingest():
             if not handle.done:
                 handle._set_error(exc)
 
