@@ -387,19 +387,20 @@ def _trace_continuous_entries(eng, model, case: IRCase, plen: int,
     import jax
     import jax.numpy as jnp
     b = eng.cfg.max_batch
+    g = eng._admit_rows             # rows per admission prefill call
     eng._ensure_pool()
     key = jax.random.PRNGKey(0)
     try:
-        batch = {"tokens": jnp.zeros((b, plen), jnp.int32),
-                 "kv_start": jnp.zeros((b,), jnp.int32), **_extras(model, b)}
+        batch = {"tokens": jnp.zeros((g, plen), jnp.int32),
+                 "kv_start": jnp.zeros((g,), jnp.int32), **_extras(model, g)}
         batch = eng._place_batch(batch)
         scratch = eng._scratch_cache(plen)
         admit = eng._admit_fn or eng._build_admit_fn()
         eng._admit_fn = admit
         out["admit"] = summarize_entry(
             "admit", admit, eng.params, batch, scratch, eng._pools,
-            eng._fixed, eng._cur, key, jnp.zeros((b, plen), jnp.int32),
-            jnp.zeros((b,), jnp.int32))
+            eng._fixed, eng._cur, key, jnp.zeros((g, plen), jnp.int32),
+            jnp.zeros((g,), jnp.int32))
     except Exception as e:
         errors["admit"] = f"{type(e).__name__}: {e}"
     try:

@@ -235,11 +235,16 @@ class Engine:
             mesh = build_mesh(mesh)
         self.mesh = mesh
         self.rules = None
+        # Rows per admission prefill call: one per shard of the batch axes
+        # (1 single-device), so a call carries the rows it admits and not
+        # every slot.
+        self._admit_rows = 1
         if mesh is not None:
             from repro.distributed import sharding as sh
             # Inference rules (TP only, no FSDP); explicit ambient rules
             # still win for callers that know better.
             self.rules = rules or sh.serving_rules(mesh)
+            self._admit_rows = sh.axis_size(mesh, self.rules.batch_axes)
             # Re-place params by the rules (no-op when they were initialised
             # by the same rules; values unchanged either way, so sharded and
             # single-device engines stay token-for-token equal).
@@ -616,12 +621,13 @@ class Engine:
         self._trace_decode_tiles()
 
     def _scratch_cache(self, plen: int):
-        """Admission prefill cache for one plen bucket, reused across
-        admissions: prefill fully overwrites its "self" columns [0, plen)
-        and recomputes every fixed leaf, so stale contents never leak."""
+        """Admission prefill cache for one plen bucket, one row per row of
+        a prefill call, reused across admissions: prefill fully overwrites
+        its "self" columns [0, plen) and recomputes every fixed leaf, so
+        stale contents never leak."""
         cache = self._scratch.get(plen)
         if cache is None:
-            cache = self.model.init_cache(self.cfg.max_batch, plen)
+            cache = self.model.init_cache(self._admit_rows, plen)
             if self.mesh is not None:
                 from repro.distributed import sharding as sh
                 cache = jax.device_put(
@@ -631,10 +637,10 @@ class Engine:
 
     @staticmethod
     def _scatter_fixed(fixed, new, slot_map):
-        """Row-scatter ``new``'s admitted rows into the resident fixed tree
-        along each leaf's batch dim (kind-aware: cross-KV at -4, SSM state
-        at -4, conv state at -3).  ``slot_map`` pads with an out-of-range
-        index, which JAX gathers clamp and scatters drop."""
+        """Row-scatter ``new``'s rows into the resident fixed tree along
+        each leaf's batch dim (kind-aware: cross-KV at -4, SSM state at -4,
+        conv state at -3): row i goes to slot ``slot_map[i]``.  ``slot_map``
+        pads with an out-of-range index, which JAX scatters drop."""
         kinds = {"cross": "kv", "ssm": "ssm", "conv": "conv"}
 
         def walk(old, upd, kind=None):
@@ -647,15 +653,16 @@ class Engine:
             bd = old.ndim - (3 if kind == "conv" else 4)
             o2 = jnp.moveaxis(old, bd, 0)
             u2 = jnp.moveaxis(upd, bd, 0)
-            return jnp.moveaxis(o2.at[slot_map].set(u2[slot_map]), 0, bd)
+            return jnp.moveaxis(o2.at[slot_map].set(u2), 0, bd)
 
         return walk(fixed, new)
 
     def _build_admit_fn(self):
-        """Jitted admission: one full-batch prefill into the plen-bucket
-        scratch cache, prompt KV scattered to its pages, fixed leaves
-        row-scattered to their slots, first token sampled into ``cur``.
-        Compiles once per plen bucket (shapes carry the key)."""
+        """Jitted admission: one prefill of a call's packed rows into the
+        plen-bucket scratch cache, prompt KV scattered to its pages, fixed
+        leaves row-scattered to their slots, first token sampled into
+        ``cur``.  The row count is fixed per engine, so this compiles once
+        per plen bucket (shapes carry the key)."""
         prefill = self.model.prefill
 
         def admit_fn(params, batch, scratch, pools, fixed, cur, key,
@@ -669,7 +676,7 @@ class Engine:
             # Split BEFORE the first sample (wave-loop key discipline).
             key, sub = jax.random.split(key)
             first = self._sample(logits0, sub)
-            cur_out = cur.at[slot_map].set(first[slot_map])
+            cur_out = cur.at[slot_map].set(first)
             # logits0 rides out so admission can snapshot each admitted
             # row's last-position logits into the prefix cache.
             return pools_out, fixed_out, cur_out, key, logits0
@@ -1104,9 +1111,15 @@ class Engine:
                      extra_inputs: Optional[Dict[str, jax.Array]],
                      key: jax.Array) -> jax.Array:
         """Admit every queue-head request that fits (slot + prompt pages),
-        consult the prefix cache for each, then prefill the misses in ONE
-        batched call and insert their prompt KV, fixed-leaf rows and first
-        sampled token into the live state.
+        consult the prefix cache for each, then prefill the misses and
+        insert their prompt KV, fixed-leaf rows and first sampled token
+        into the live state.
+
+        The misses are prefilled in calls of ``_admit_rows`` rows (one per
+        shard of the mesh's batch axes; 1 single-device), sorted by prompt
+        length, each call padded to the bucket of its own longest prompt:
+        no call computes slots that are not being admitted, beyond the pad
+        rows of a short last call on a mesh.
 
         Prefix-cache composition (all host bookkeeping):
 
@@ -1116,7 +1129,7 @@ class Engine:
         * when the head still doesn't fit, the cache evicts LRU entries
           before admission blocks (matching entries are re-resolved each
           retry — the evicted item may have been the match);
-        * full-prompt hits skip the batched prefill entirely
+        * full-prompt hits skip the prefill entirely
           (:meth:`_restore_hits`); partial hits prefill the whole prompt
           for exactness but redirect shared-column writes to TRASH;
         * every prefilled prompt (cache enabled, no extras) is inserted
@@ -1124,7 +1137,8 @@ class Engine:
 
         While a capture records, each admitted request leaves one
         ``serve.request`` event (its waits before admission) inside
-        ``serve.admit.plan``.
+        ``serve.admit.plan``, and each prefill call one ``serve.admit``
+        span; all of them carry the pass's ``admit_id``.
         """
         from repro.profiling import annotate, recording
         tracing = recording()
@@ -1174,9 +1188,10 @@ class Engine:
                                            * 1e6),
                             hit=0 if m is None else 2 if m.full else 1):
                         pass
-            if admitted:
-                plen, toks, kv_start, dest, slot_map = self._plan_admission(
-                    admitted)
+            g = self._admit_rows
+            ordered = sorted(admitted, key=lambda r: len(r.prompt))
+            calls = [self._plan_admission(ordered[i:i + g])
+                     for i in range(0, len(ordered), g)]
         if admitted or hits:
             self._admit_passes += 1
         if hits:
@@ -1184,89 +1199,90 @@ class Engine:
         if not admitted:
             return key
 
-        cfg = self.cfg
-        b = cfg.max_batch
         if self._admit_fn is None:
             self._admit_fn = self._build_admit_fn()
-        t0 = time.perf_counter()
-        with annotate("serve.admit") as span:
-            if tracing:
-                span.set_metadata(
-                    admit_id=admit_id, rows=len(admitted), batch=b,
-                    bucket=plen,
-                    prompt_tokens=sum(len(r.prompt) for r in admitted),
-                    cached_tokens=sum(
-                        r.cached_prefix_tokens for r in admitted
-                        if r.prefix_hit == api.PREFIX_HIT_PARTIAL))
-            batch = {"tokens": jnp.asarray(toks),
-                     "kv_start": jnp.asarray(kv_start)}
-            if extra_inputs:
-                rows = [r.row for r in admitted]
-                slots = [r.slot for r in admitted]
-                for name, arr in extra_inputs.items():
-                    padded = jnp.zeros((b,) + arr.shape[1:], arr.dtype)
-                    batch[name] = padded.at[jnp.asarray(slots)].set(
-                        jnp.asarray(arr)[jnp.asarray(rows)])
-            batch = self._place_batch(batch)
-            scratch = self._scratch_cache(plen)
-            self._record_prefill_flash_tiles(plen)
-            self._plen_buckets.add(int(plen))
-            (self._pools, self._fixed, self._cur, key,
-             logits0) = self._admit_fn(
-                self.params, batch, scratch, self._pools, self._fixed,
-                self._cur, key, jnp.asarray(dest), jnp.asarray(slot_map))
-            if cfg.profile:
-                # deliberate sync: profile mode wants the true prefill /
-                # decode wall-time split, not dispatch-pipeline overlap
-                jax.block_until_ready(self._cur)   # analysis: allow(TP001)
-        self._stats["prefill_seconds"] += time.perf_counter() - t0
-        self._stats["admission_prefills"] += 1
+        prefilled = []                  # (call's requests, its logits0)
+        for rows, plen, toks, kv_start, dest, slot_map in calls:
+            t0 = time.perf_counter()
+            with annotate("serve.admit") as span:
+                if tracing:
+                    span.set_metadata(
+                        admit_id=admit_id, rows=len(rows), batch=g,
+                        bucket=plen,
+                        prompt_tokens=sum(len(r.prompt) for r in rows),
+                        cached_tokens=sum(
+                            r.cached_prefix_tokens for r in rows
+                            if r.prefix_hit == api.PREFIX_HIT_PARTIAL))
+                batch = {"tokens": jnp.asarray(toks),
+                         "kv_start": jnp.asarray(kv_start)}
+                if extra_inputs:
+                    idx = jnp.asarray([r.row for r in rows])
+                    for name, arr in extra_inputs.items():
+                        padded = jnp.zeros((g,) + arr.shape[1:], arr.dtype)
+                        batch[name] = padded.at[:len(rows)].set(
+                            jnp.asarray(arr)[idx])
+                batch = self._place_batch(batch)
+                scratch = self._scratch_cache(plen)
+                self._record_prefill_flash_tiles(plen)
+                self._plen_buckets.add(int(plen))
+                (self._pools, self._fixed, self._cur, key,
+                 logits0) = self._admit_fn(
+                    self.params, batch, scratch, self._pools, self._fixed,
+                    self._cur, key, jnp.asarray(dest), jnp.asarray(slot_map))
+                if self.cfg.profile:
+                    # deliberate sync: profile mode wants the true prefill /
+                    # decode wall-time split, not dispatch-pipeline overlap
+                    jax.block_until_ready(self._cur)   # analysis: allow(TP001)
+            self._stats["prefill_seconds"] += time.perf_counter() - t0
+            self._stats["admission_prefills"] += 1
+            prefilled.append((rows, logits0))
         if caching:
             # Insert while the rows' pages are known-live: the cache takes
             # its own refs, so the entries outlive the rows.
             with annotate("serve.prefix_insert"):
-                for r in admitted:
-                    row = self._csched.rows[r.slot]
-                    self._prefix.insert(r.prompt, row.pages,
-                                        logits0[r.slot],
-                                        self._slice_fixed_row(r.slot))
+                for rows, logits0 in prefilled:
+                    for i, r in enumerate(rows):
+                        row = self._csched.rows[r.slot]
+                        self._prefix.insert(r.prompt, row.pages, logits0[i],
+                                            self._slice_fixed_row(r.slot))
         return key
 
-    def _plan_admission(self, admitted: List[_Request]):
-        """Host inputs of one batched admission prefill: the bucket
-        ``plen``, right-aligned tokens, per-row ``kv_start``, each
-        column's KV destination in the pool, and the slot map."""
+    def _plan_admission(self, rows: List[_Request]):
+        """Host inputs of one admission prefill call over ``rows`` (at
+        most ``_admit_rows``), packed by position in the call: the bucket
+        ``plen`` of the longest prompt, right-aligned tokens, per-row
+        ``kv_start``, each column's KV destination in the pool, and the
+        slot map (the call's i-th row is slot ``slot_map[i]``)."""
         from repro.serve.kv_pages import TRASH_PAGE
-        b = self.cfg.max_batch
+        g = self._admit_rows
         page = self._page_size
-        plen = _bucket_len(max(len(r.prompt) for r in admitted))
-        toks = np.zeros((b, plen), np.int32)
-        kv_start = np.full((b,), plen, np.int32)
-        # Prompt-KV destinations: batch rows not admitted THIS call (and pad
-        # columns of admitted rows) write to the TRASH page; real columns
-        # map straight into the row's block table.  Columns covered by a
-        # partial prefix hit ALSO write to TRASH — their pages are shared
-        # read-only with the cache, and the cached KV is already what this
-        # prefill would write (pages-written saving, dedup'd pool memory).
+        plen = _bucket_len(max(len(r.prompt) for r in rows))
+        toks = np.zeros((g, plen), np.int32)
+        kv_start = np.full((g,), plen, np.int32)
+        # Prompt-KV destinations: pad rows of a short call (and pad columns
+        # of real rows) write to the TRASH page; real columns map straight
+        # into the row's block table.  Columns covered by a partial prefix
+        # hit ALSO write to TRASH — their pages are shared read-only with
+        # the cache, and the cached KV is already what this prefill would
+        # write (pages-written saving, dedup'd pool memory).
         dest = np.broadcast_to(TRASH_PAGE * page + np.arange(plen) % page,
-                               (b, plen)).astype(np.int32).copy()
-        for r in admitted:
+                               (g, plen)).astype(np.int32).copy()
+        for i, r in enumerate(rows):
             row = self._csched.rows[r.slot]
             np_prompt = len(r.prompt)
-            toks[r.slot, plen - np_prompt:] = r.prompt
-            kv_start[r.slot] = plen - np_prompt
+            toks[i, plen - np_prompt:] = r.prompt
+            kv_start[i] = plen - np_prompt
             shared_toks = (r.cached_prefix_tokens
                            if r.prefix_hit == api.PREFIX_HIT_PARTIAL else 0)
             logical = np.arange(shared_toks, np_prompt)
             pages = np.asarray(row.pages, np.int64)
-            dest[r.slot, plen - np_prompt + shared_toks:] = (
+            dest[i, plen - np_prompt + shared_toks:] = (
                 pages[logical // page] * page + logical % page)
-        # slot_map pads with the out-of-range index b: JAX clamps it on
-        # gather (the garbage row is immediately discarded) and drops it on
-        # scatter, so non-admitted slots keep their live state untouched.
-        slot_map = np.full((b,), b, np.int32)
-        slot_map[:len(admitted)] = [r.slot for r in admitted]
-        return plen, toks, kv_start, dest, slot_map
+        # slot_map pads with the out-of-range index max_batch: JAX drops it
+        # on scatter, so a pad row leaves every live slot untouched.
+        slot_map = np.full((g,), self.cfg.max_batch, np.int32)
+        slot_map[:len(rows)] = [r.slot for r in rows]
+        return rows, plen, toks, kv_start, dest, slot_map
 
     def _run_chunk(self, key: jax.Array):
         """One fused decode chunk over every live row; returns the updated

@@ -62,7 +62,9 @@ STATS_SCHEMA: Dict[str, StatKey] = {
     "chunks": StatKey("traffic", ALWAYS,
                       "continuous-scheduler fused decode chunks executed"),
     "admission_prefills": StatKey("traffic", ALWAYS,
-                                  "batched admission prefill calls"),
+                                  "admission prefill calls: one per group "
+                                  "of rows the size of the mesh's batch "
+                                  "axes (1 alone), several per pass"),
     "device_transfers": StatKey("traffic", ALWAYS,
                                 "device->host fetches (one per chunk/wave)"),
     "cache_allocs": StatKey("traffic", ALWAYS,

@@ -131,6 +131,19 @@ def test_mesh_continuous_vs_wave_parity():
                   ServeConfig(max_batch=8, max_len=64, mesh="data=4,model=2",
                               scheduler="wave"))
     assert cont.generate(prompts, 5) == wave.generate(prompts, 5)
+    # rows of very different lengths in one pass (two calls of 4 rows, at
+    # buckets 32 and 64), then a partial prefix hit beside short rows
+    v = cfg.vocab_size
+    long_ = [(7 * i + 3) % v for i in range(40)]
+    mixed = [[2, 5, 1], long_, [(5 * i + 1) % v for i in range(20)],
+             [4, 4, 1, 2, 9], [(3 * i) % v for i in range(33)], [6] * 12]
+    partial = [long_[:32] + [1, 2, 3], [9, 4],
+               [(3 * i + 2) % v for i in range(27)]]
+    calls0 = cont.stats()["admission_prefills"]
+    assert cont.generate(mixed, 5) == wave.generate(mixed, 5)
+    assert cont.stats()["admission_prefills"] - calls0 == 2
+    assert cont.generate(partial, 5) == wave.generate(partial, 5)
+    assert cont.stats()["prefix_cache"]["hits_partial"] >= 1
     st = cont.stats()
     assert st["scheduler"] == "continuous"
     assert st["chunks"] >= 1 and st["admissions"] >= len(prompts)
@@ -396,6 +409,82 @@ _PER_SHARD = textwrap.dedent("""
         "parity": out1 == out4,
         "local": many.stats()["decode_tile_lookups"]}))
 """)
+
+
+_ADMIT_ROWS = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax, numpy as np
+    from repro.configs.catalog import ARCHITECTURES
+    from repro.models import build_model
+    from repro.serve import Engine, Request, ServeConfig
+    from repro.serve.kv_pages import TRASH_PAGE
+
+    cfg = ARCHITECTURES["llama3.2-1b"].reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    kw = dict(max_batch=4, max_len=64, page_size=16)
+    one = Engine(model, params, ServeConfig(**kw))
+    four = Engine(model, params, ServeConfig(mesh="data=4,model=1", **kw))
+    real = four._build_admit_fn()
+    calls = []
+
+    def recording(params, batch, scratch, pools, fixed, cur, key, dest,
+                  slot_map):
+        calls.append({"shape": list(batch["tokens"].shape),
+                      "kv_start": np.asarray(batch["kv_start"]).tolist(),
+                      "slot_map": np.asarray(slot_map).tolist(),
+                      "dest_pages": np.unique(
+                          np.asarray(dest)[3] // 16).tolist()})
+        return real(params, batch, scratch, pools, fixed, cur, key, dest,
+                    slot_map)
+
+    four._admit_fn = recording
+    # a long-lived row and three that finish in the first chunk fill the
+    # four slots; the next pass admits three rows beside the live one
+    reqs = [([(5 * i + 1) % cfg.vocab_size for i in range(20)], 24),
+            ([3, 1, 4], 2), ([1, 5, 9, 2], 2), ([6, 5], 2),
+            ([8, 9, 7], 3), ([(3 * i) % cfg.vocab_size for i in range(30)], 3),
+            ([2] * 9, 3)]
+    out = {}
+    for name, eng in (("one", one), ("four", four)):
+        hs = [eng.submit(Request(prompt=p, max_new_tokens=n)) for p, n in reqs]
+        eng.run()
+        out[name] = [h.result(timeout=0).tokens for h in hs]
+    print("RESULT " + json.dumps({
+        "calls": calls, "parity": out["one"] == out["four"],
+        "prefills": [one.stats()["admission_prefills"],
+                     four.stats()["admission_prefills"]],
+        "trash": TRASH_PAGE}))
+""")
+
+
+def test_admission_calls_take_the_data_axis_rows():
+    """On a data=4 mesh of four CPU devices a prefill call carries four
+    rows: a pass admitting three makes one call with one pad row, whose
+    prompt KV goes only to TRASH and whose out-of-range slot leaves the
+    live row untouched (every row serves the single-device tokens)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _ADMIT_ROWS],
+                          capture_output=True, text=True, env=env,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][-1]
+    rec = json.loads(line[len("RESULT "):])
+    first, second = rec["calls"]
+    # rows sorted by prompt length: 2, 3, 4 and 20 tokens
+    assert first["shape"] == [4, 32] and first["slot_map"] == [3, 1, 2, 0]
+    # rows sorted by prompt length (3, 9, 30 tokens), then the pad row
+    assert second["shape"] == [4, 32]
+    assert second["slot_map"] == [1, 3, 2, 4]          # 4 = max_batch: pad
+    assert second["kv_start"] == [29, 23, 2, 32]
+    assert second["dest_pages"] == [rec["trash"]]
+    assert rec["prefills"] == [7, 2]                   # one row per call alone
+    assert rec["parity"], rec
 
 
 def test_pallas_kernels_run_per_shard_on_a_mesh():

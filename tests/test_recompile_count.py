@@ -122,6 +122,22 @@ def test_continuous_steady_state_zero_recompiles(continuous_setup):
     assert out1 == out2
 
 
+def test_continuous_admission_compiles_once_per_plen_bucket(
+        continuous_setup):
+    """Admission prefills each row alone at its own bucket, and the call's
+    row count is fixed per engine: one compile per plen bucket, however
+    rows of different lengths share a pass."""
+    cfg, eng = continuous_setup
+    _gen(eng, cfg, [3, 12, 30, 5], 4)       # buckets 8, 16, 32 in one pass
+    _gen(eng, cfg, [30], 4)
+    _gen(eng, cfg, [12, 3], 4)
+    buckets = eng.stats()["prefill_plen_buckets"]
+    assert {8, 16, 32} <= set(buckets)
+    assert eng._admit_fn._cache_size() == len(buckets), (
+        f"{eng._admit_fn._cache_size()} admission compiles for plen "
+        f"buckets {buckets}")
+
+
 def test_continuous_one_device_get_per_chunk(continuous_setup, monkeypatch):
     """The continuous drain's host-transfer contract: exactly one
     device_get per decode chunk — admission, eviction and block-table

@@ -5,6 +5,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs.catalog import ARCHITECTURES
@@ -394,6 +395,35 @@ def test_continuous_matches_wave_engine_all_families(arch):
                                  extra_inputs=extra or None)
     assert out_c == oracle, arch
     assert eng_c.stats()["scheduler"] == "continuous"
+    # one pass admitting rows at buckets 8, 64 and 32, then one with a
+    # partial prefix hit on the longest (two 16-token pages) beside others.
+    # The MoE layer routes left-pad tokens into expert capacity, so its
+    # tokens depend on the pad length: there each row must serve what it
+    # serves alone (at its own bucket, as admission now prefills it),
+    # while the wave engine pads every row to the longest one's bucket.
+    # max_len 128 keeps the wave engine's bucket for 40 tokens at 64 (max_len
+    # 64 clamps it to 56).
+    eng_c = Engine(model, params, ServeConfig(max_batch=3, max_len=128))
+    eng_w = Engine(model, params, ServeConfig(max_batch=3, max_len=128,
+                                              scheduler="wave"))
+    solo = Engine(model, params, ServeConfig(max_batch=3, max_len=128,
+                                             prefix_cache=False))
+    v = cfg.vocab_size
+    long_ = [(7 * i + 3) % v for i in range(40)]
+    mixed = [[2, 5, 1], long_, [(5 * i + 1) % v for i in range(20)]]
+    partial = [long_[:32] + [1, 2, 3], [9, 4],
+               [(3 * i + 2) % v for i in range(27)]]
+    for prompts in (mixed, partial):
+        extra = {k: jnp.zeros((len(prompts),) + s.shape[1:], s.dtype)
+                 for k, s in model.extra_inputs(len(prompts)).items()}
+        out = eng_c.generate(prompts, 5, extra_inputs=extra or None)
+        if cfg.family == "moe":
+            assert out == [solo.generate([p], 5)[0] for p in prompts], arch
+        else:
+            assert out == eng_w.generate(prompts, 5,
+                                         extra_inputs=extra or None), arch
+    if not extra:           # requests with extra inputs are never cached
+        assert eng_c.stats()["prefix_cache"]["hits_partial"] >= 1, arch
 
 
 def test_continuous_falls_back_to_wave_for_ssm_and_kv_quant():
@@ -474,3 +504,58 @@ def test_continuous_preemption_restart_is_exact():
     for h, p in zip(handles, prompts):
         assert h.result(timeout=0).tokens == generate_per_prompt(
             model, params, [p], 10, max_len=64)[0]
+
+
+def test_admission_prefills_each_row_alone_at_its_own_bucket():
+    """Single device: one pass admitting prompts of 1500, 40 and 300 tokens
+    makes three one-row prefill calls, shortest first, at buckets 64, 512
+    and 2048; each call's tokens are right-aligned at its own bucket, its
+    KV columns land in the row's pages (pad columns in TRASH) and its slot
+    map names the row's slot."""
+    from repro.serve.kv_pages import TRASH_PAGE
+    cfg, model, params, eng = _build(max_len=2048, page_size=16)
+    v = cfg.vocab_size
+    prompts = [[(i * 7 + 1) % v for i in range(1500)],
+               [(i * 5 + 2) % v for i in range(40)],
+               [(i * 3 + 4) % v for i in range(300)]]
+    eng._ensure_pool()
+    real = eng._build_admit_fn()
+    calls = []
+
+    def recording(params, batch, scratch, pools, fixed, cur, key, dest,
+                  slot_map):
+        slot = int(slot_map[0])
+        calls.append(dict(tokens=np.asarray(batch["tokens"]),
+                          kv_start=np.asarray(batch["kv_start"]),
+                          dest=np.asarray(dest),
+                          slot_map=np.asarray(slot_map),
+                          pages=list(eng._csched.rows[slot].pages)))
+        return real(params, batch, scratch, pools, fixed, cur, key, dest,
+                    slot_map)
+
+    eng._admit_fn = recording
+    handles = [eng.submit(Request(prompt=p, max_new_tokens=2))
+               for p in prompts]
+    eng.run()
+    assert eng.stats()["admission_prefills"] == 3
+    page = 16
+    # slots go in arrival order (0, 1, 2), calls by prompt length
+    for c, (slot, n, plen) in zip(calls, [(1, 40, 64), (2, 300, 512),
+                                          (0, 1500, 2048)]):
+        assert c["tokens"].shape == (1, plen)
+        assert c["slot_map"].tolist() == [slot]
+        assert c["kv_start"].tolist() == [plen - n]
+        assert c["tokens"][0, plen - n:].tolist() == prompts[slot]
+        assert not c["tokens"][0, :plen - n].any()
+        logical = np.arange(n)
+        pages = np.asarray(c["pages"])
+        assert c["dest"][0, plen - n:].tolist() == (
+            pages[logical // page] * page + logical % page).tolist()
+        assert (c["dest"][0, :plen - n] // page == TRASH_PAGE).all()
+    assert len(calls) == 3
+    assert eng.stats()["prefill_plen_buckets"] == [64, 512, 2048]
+    # the wave engine, prefilling all three at 2048, serves the same tokens
+    wave = Engine(model, params, ServeConfig(max_batch=3, max_len=2048,
+                                             scheduler="wave"))
+    assert [h.result(timeout=0).tokens for h in handles] == \
+        wave.generate(prompts, 2)

@@ -17,6 +17,7 @@ from repro import profiling
 from repro.configs.catalog import ARCHITECTURES
 from repro.models import build_model
 from repro.serve import Engine, Request, ServeConfig, Server
+from repro.serve.engine import _bucket_len
 
 #: the phases of one pass of the continuous loop, in loop order
 PHASES = ["serve.ingest", "serve.admit.plan", "serve.prefix_restore",
@@ -77,10 +78,10 @@ def test_phases_in_loop_order_with_one_event_per_admission(engine, tmp_path):
     rank = [PHASES.index(e["name"]) for e in phases]
     for a, b in zip(rank, rank[1:]):
         # forward through the pass, or back to the next pass's ingest;
-        # serve.chunk.plan twice (pages and preemption, then the chunk's
-        # indices)
-        assert b > a or b == 0 or PHASES[a] == PHASES[b] == \
-            "serve.chunk.plan", (PHASES[a], PHASES[b])
+        # serve.admit once per prefill call; serve.chunk.plan twice (pages
+        # and preemption, then the chunk's indices)
+        assert b > a or b == 0 or (a == b and PHASES[a] in (
+            "serve.admit", "serve.chunk.plan")), (PHASES[a], PHASES[b])
     for a, b in zip(phases, phases[1:]):
         assert a["ts"] + a["dur"] <= b["ts"] + 1e-3     # flat: no nesting
         if a["name"] == "serve.chunk":
@@ -114,21 +115,50 @@ def test_phases_in_loop_order_with_one_event_per_admission(engine, tmp_path):
         assert a["hit"] == hit_code[by_rid[a["rid"]].prefix_hit]
     assert sorted(_iargs(e)["hit"] for e in reqs) == [0, 0, 0, 1, 2]
 
-    # each admission's prompt tokens are its admitted (prefilled) prompts'
+    # one serve.admit per prefilled request (one row per call on a single
+    # device), at its own prompt's bucket; a pass's calls share its
+    # admit_id and together carry its admitted (prefilled) prompts
     admits = [_iargs(e) for e in phases if e["name"] == "serve.admit"]
     assert admits
     for a in admits:
+        assert a["rows"] == a["batch"] == 1
+        assert a["bucket"] == _bucket_len(a["prompt_tokens"])
+    for admit_id in {a["admit_id"] for a in admits}:
+        calls = [a for a in admits if a["admit_id"] == admit_id]
         mine = [by_rid[_iargs(e)["rid"]] for e in reqs
-                if _iargs(e)["admit_id"] == a["admit_id"]
+                if _iargs(e)["admit_id"] == admit_id
                 and _iargs(e)["hit"] != 2]
-        assert a["rows"] == len(mine)
-        assert a["prompt_tokens"] == sum(r.prompt_len for r in mine)
-        assert a["cached_tokens"] == sum(r.cached_prefix_tokens
-                                         for r in mine
-                                         if r.prefix_hit == "partial")
-        assert a["batch"] == 3
-        assert a["bucket"] >= max(r.prompt_len for r in mine)
+        assert len(calls) == len(mine)
+        assert sorted(a["prompt_tokens"] for a in calls) == \
+            sorted(r.prompt_len for r in mine)
+        assert sum(a["cached_tokens"] for a in calls) == sum(
+            r.cached_prefix_tokens for r in mine if r.prefix_hit == "partial")
     assert sum(a["cached_tokens"] for a in admits) == 16
+
+
+def test_one_admit_span_per_prefill_call(engine, tmp_path):
+    """A pass that admits k prompts of different lengths on one device
+    makes k prefill calls of one row, each at its own prompt's bucket,
+    under one admit_id; their prompt tokens sum to the pass's prompts."""
+    engine.clear_prefix_cache()
+    calls0 = engine.stats()["admission_prefills"]
+    prompts = [[4] * 40, [5] * 3, [6] * 20]
+    with profiling.trace(str(tmp_path / "cap")) as s:
+        handles = [engine.submit(Request(prompt=p, max_new_tokens=4))
+                   for p in prompts]
+        engine.run()
+    assert all(h.result(timeout=0).finish_reason for h in handles)
+    admits = [_iargs(e) for e in _spans(s.events())
+              if e["name"] == "serve.admit"]
+    assert len(admits) == len(prompts)
+    assert engine.stats()["admission_prefills"] - calls0 == len(prompts)
+    assert len({a["admit_id"] for a in admits}) == 1
+    assert [(a["rows"], a["batch"]) for a in admits] == [(1, 1)] * 3
+    # shortest first: a call's rows are sorted by prompt length
+    assert [(a["prompt_tokens"], a["bucket"]) for a in admits] == \
+        [(3, 8), (20, 32), (40, 64)]
+    assert sum(a["prompt_tokens"] for a in admits) == sum(map(len, prompts))
+    assert all(a["cached_tokens"] == 0 for a in admits)
 
 
 class _Span:
