@@ -6,8 +6,8 @@ the kernel in an op-keyed registry fed by a persistent tuning database.
 """
 from repro.core.attention_api import flash_attention  # noqa: F401
 from repro.core.gemm_api import (  # noqa: F401
-    ExecutionContext, capture_gemm_shapes, current_hardware, einsum,
-    execution_context, matmul,
+    ExecutionContext, allreduce_repeat, allreduce_tally, capture_gemm_shapes,
+    current_hardware, einsum, execution_context, matmul,
 )
 from repro.core.hardware import (  # noqa: F401
     CPU_INTERPRET, GPU_GENERIC, HARDWARE, HOST_CPU, HardwareProfile,

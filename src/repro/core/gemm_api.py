@@ -75,6 +75,49 @@ def execution_context(**overrides):
         _TLS.ctx = old
 
 
+@dataclasses.dataclass
+class AllreduceTally:
+    """Bytes the explicit cross-shard reductions of a traced program move:
+    each adds its per-shard operand (shape x itemsize) once per run."""
+    bytes: int = 0
+
+
+@contextlib.contextmanager
+def allreduce_tally():
+    """Count, while a program is traced under this scope, the operand
+    bytes of every explicit cross-shard reduction in it (the row-parallel
+    GEMM's ``psum``).  Counting happens at trace time only: a compiled
+    program that is run again adds nothing, so the caller keeps the count
+    per compiled program.  Reductions the compiler inserts itself (around
+    a sharded embedding or logits) are not counted."""
+    tally = AllreduceTally()
+    stack = _TLS.__dict__.setdefault("tallies", [])
+    stack.append(tally)
+    try:
+        yield tally
+    finally:
+        stack.pop()
+
+
+@contextlib.contextmanager
+def allreduce_repeat(n: int):
+    """Scale what a loop body traced under this scope adds to the open
+    tallies by the loop's trip count ``n`` (a layer ``scan`` traces its
+    body once and runs it ``n`` times)."""
+    old = getattr(_TLS, "repeat", 1)
+    _TLS.repeat = old * n
+    try:
+        yield
+    finally:
+        _TLS.repeat = old
+
+
+def _count_allreduce(x: jax.Array) -> None:
+    nbytes = x.size * jnp.dtype(x.dtype).itemsize * getattr(_TLS, "repeat", 1)
+    for tally in getattr(_TLS, "tallies", ()):
+        tally.bytes += nbytes
+
+
 def current_hardware() -> str:
     """Resolved registry/tuner hardware key of the ambient execution context.
 
@@ -262,6 +305,7 @@ def _per_shard_gemm(policy, layout, hardware: str, backend: str, x2, w,
                             activation=activation, out_dtype=out_dtype)
         part = ops.gemm(xl, wl, config=config, backend=backend,
                         out_dtype=jnp.float32)
+        _count_allreduce(part)
         return apply_epilogue(jax.lax.psum(part, k_ax), bias=bl,
                               activation=activation).astype(out_dtype)
 

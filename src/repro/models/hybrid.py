@@ -110,7 +110,7 @@ def forward_hidden(cfg: ModelConfig, params, batch: Dict[str, jax.Array]):
     if cfg.family == "ssm":
         def body(x, bp):
             return _mamba_block(cfg, bp, x), None
-        x, _ = jax.lax.scan(T._maybe_remat(cfg, body), x, params["blocks"])
+        x, _ = T.layer_scan(T._maybe_remat(cfg, body), x, params["blocks"])
     else:
         pos = T._positions(b, s)
 
@@ -119,10 +119,10 @@ def forward_hidden(cfg: ModelConfig, params, batch: Dict[str, jax.Array]):
 
             def inner(xx, bp):
                 return _mamba_block(cfg, bp, xx), None
-            x, _ = jax.lax.scan(inner, x, unit_params)
+            x, _ = T.layer_scan(inner, x, unit_params)
             return x, None
 
-        x, _ = jax.lax.scan(T._maybe_remat(cfg, unit_body), x, params["units"])
+        x, _ = T.layer_scan(T._maybe_remat(cfg, unit_body), x, params["units"])
     return x, jnp.float32(0.0)
 
 
@@ -176,7 +176,7 @@ def prefill(cfg: ModelConfig, params, batch, cache):
         def body(x, bp):
             x, state = _mamba_block_prefill(cfg, bp, x, valid=valid)
             return x, state
-        x, new_states = jax.lax.scan(body, x, params["blocks"])
+        x, new_states = T.layer_scan(body, x, params["blocks"])
         logits = T._unembed(cfg, params, x[:, -1:, :])[:, 0]
         return logits, {"ssm": jax.tree_util.tree_map(
             lambda old, new: new.astype(old.dtype), cache["ssm"], new_states)}
@@ -190,10 +190,10 @@ def prefill(cfg: ModelConfig, params, batch, cache):
 
         def inner(xx, bp):
             return _mamba_block_prefill(cfg, bp, xx, valid=valid)
-        x, states = jax.lax.scan(inner, x, unit_params)
+        x, states = T.layer_scan(inner, x, unit_params)
         return x, (states, new_kv)
 
-    x, (new_states, new_self) = jax.lax.scan(
+    x, (new_states, new_self) = T.layer_scan(
         T._maybe_remat(cfg, unit_body), x, (params["units"], cache["self"]))
     logits = T._unembed(cfg, params, x[:, -1:, :])[:, 0]
     new_states = jax.tree_util.tree_map(
@@ -214,7 +214,7 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, offset, kv_start=None):
             bp, state = xs
             x, new_state = _mamba_block_step(cfg, bp, x, state)
             return x, new_state
-        x, new_states = jax.lax.scan(body, x, (params["blocks"], cache["ssm"]))
+        x, new_states = T.layer_scan(body, x, (params["blocks"], cache["ssm"]))
         logits = T._unembed(cfg, params, x)[:, 0]
         return logits, {"ssm": new_states}
 
@@ -229,10 +229,10 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, offset, kv_start=None):
             bp, st = ys
             xx, new_st = _mamba_block_step(cfg, bp, xx, st)
             return xx, new_st
-        x, new_states = jax.lax.scan(inner, x, (unit_params, states))
+        x, new_states = T.layer_scan(inner, x, (unit_params, states))
         return x, (new_states, new_kv)
 
-    x, (new_states, new_self) = jax.lax.scan(
+    x, (new_states, new_self) = T.layer_scan(
         unit_body, x, (params["units"], cache["ssm"], cache["self"]))
     logits = T._unembed(cfg, params, x)[:, 0]
     return logits, {"ssm": new_states, "self": new_self}

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core import matmul
+from repro.core import allreduce_repeat, matmul
 from repro.distributed.ctx import constrain
 from repro.models import layers as L
 from repro.models import moe as M
@@ -163,6 +163,15 @@ def _maybe_remat(cfg: ModelConfig, fn):
     return jax.checkpoint(fn)
 
 
+def layer_scan(fn, init, xs):
+    """``jax.lax.scan`` over a stack's leading layer (or unit) axis.  A
+    scan traces its body once and runs it once per layer, so what the
+    body's explicit all-reduces add to an open ``allreduce_tally`` is
+    scaled by that trip count (nested scans multiply)."""
+    with allreduce_repeat(jax.tree_util.tree_leaves(xs)[0].shape[0]):
+        return jax.lax.scan(fn, init, xs)
+
+
 # ---------------------------------------------------------------------------
 # Decoder-only stacks (dense / moe)
 # ---------------------------------------------------------------------------
@@ -182,7 +191,7 @@ def _run_dense_stack(cfg, blocks, x, positions, caches=None, cache_offset=None,
         return (constrain(x, "hidden"), aux + a), new_cache
 
     xs = (blocks, caches) if has_cache else blocks
-    (x, aux), new_caches = jax.lax.scan(_maybe_remat(cfg, body), (x, 0.0), xs)
+    (x, aux), new_caches = layer_scan(_maybe_remat(cfg, body), (x, 0.0), xs)
     return x, (new_caches if has_cache else None), aux
 
 
@@ -399,7 +408,7 @@ def _run_vlm_stack(cfg, params, x, positions, image_embeds=None,
             return (constrain(xx, "hidden"), a + da), nc
 
         ys = (selfs, scache) if has_cache else selfs
-        (x, aux), new_scache = jax.lax.scan(inner, (x, aux), ys)
+        (x, aux), new_scache = layer_scan(inner, (x, aux), ys)
         x = constrain(_cross_block(cfg, cross_p, x, (ck, cv)), "hidden")
         out = new_scache if has_cache else 0.0
         return (x, aux), out
@@ -407,7 +416,7 @@ def _run_vlm_stack(cfg, params, x, positions, image_embeds=None,
     u = params["units"]
     ks, vs = cross_cache
     xs = (u["selfs"], u["cross"], ks, vs) + ((self_caches,) if has_cache else ())
-    (x, aux), new_caches = jax.lax.scan(_maybe_remat(cfg, unit_body), (x, 0.0), xs)
+    (x, aux), new_caches = layer_scan(_maybe_remat(cfg, unit_body), (x, 0.0), xs)
     return x, (new_caches if has_cache else None), aux
 
 
@@ -429,7 +438,7 @@ def _run_encoder(cfg, params, encoder_embeds):
         x = x + L.mlp_gelu(bp["mlp"], L.apply_norm(bp["ln2"], x, eps=cfg.norm_eps))
         return constrain(x, "hidden"), None
 
-    x, _ = jax.lax.scan(_maybe_remat(cfg, body), x, params["enc_blocks"])
+    x, _ = layer_scan(_maybe_remat(cfg, body), x, params["enc_blocks"])
     return L.apply_norm(params["enc_ln_f"], x, eps=cfg.norm_eps)
 
 
@@ -470,5 +479,5 @@ def _run_whisper_decoder(cfg, params, x, positions, enc, cross_cache=None,
 
     ks, vs = cross_cache
     xs = (params["dec_blocks"], ks, vs) + ((self_caches,) if has_cache else ())
-    (x, aux), new_caches = jax.lax.scan(_maybe_remat(cfg, body), (x, 0.0), xs)
+    (x, aux), new_caches = layer_scan(_maybe_remat(cfg, body), (x, 0.0), xs)
     return x, (new_caches if has_cache else None), aux

@@ -301,6 +301,12 @@ class Engine:
         # submitted mid-drain (see repro.serve.server.Server).
         self._ingest_hook: Optional[Callable] = None
         self._admit_passes = 0            # admission passes (admit_id)
+        # Explicit all-reduce bytes (core.allreduce_tally), counted when a
+        # program is traced: per admission run by plen bucket, per decode
+        # step by view width; their sum over the runs made so far.
+        self._admit_allreduce: Dict[int, int] = {}
+        self._step_allreduce: Dict[int, int] = {}
+        self._allreduce_bytes = 0
         self._lat_ttft: List[float] = []  # finished-request TTFT records
         self._lat_tok: List[float] = []   # finished-request tok/s records
         self._stats: Dict[str, float] = {
@@ -578,32 +584,33 @@ class Engine:
         npp = self._alloc.num_pages * self._page_size
         template = self.model.init_cache(self.cfg.max_batch, 1)
 
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+        from repro.distributed import sharding as sh
+        mesh = self.mesh
+        ta = self.rules.tensor_axis if mesh is not None else None
+
         def pool_leaf(leaf):
-            # (lead..., B, 1, kvh, hd) -> (lead..., num_pages*page, kvh, hd)
-            return jnp.zeros(leaf.shape[:-4] + (npp,) + leaf.shape[-2:],
-                             leaf.dtype)
+            # (lead..., B, 1, kvh, hd) -> (lead..., num_pages*page, kvh, hd).
+            # On a mesh each device makes its own shard: a whole pool made
+            # on one device and then split would hold it twice there (yi-9b
+            # on four chips: 4.8 GB beside 4.4 GB of weights).
+            shape = leaf.shape[:-4] + (npp,) + leaf.shape[-2:]
+            sharding = None
+            if mesh is not None:
+                # no batch dim on the flat pool: shard KV heads over the
+                # tensor axis when divisible, replicate otherwise
+                spec = [None] * len(shape)
+                if ta and shape[-2] % sh.axis_size(mesh, ta) == 0:
+                    spec[-2] = ta
+                sharding = NamedSharding(mesh, P(*spec))
+            return jnp.zeros(shape, leaf.dtype, device=sharding)
 
         pools = jax.tree_util.tree_map(pool_leaf, template["self"])
         fixed = {k: v for k, v in template.items() if k != "self"}
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
-            from repro.distributed import sharding as sh
-            ta = self.rules.tensor_axis
-
-            def pool_sharding(x):
-                # no batch dim on the flat pool: shard KV heads over the
-                # tensor axis when divisible, replicate otherwise
-                spec = [None] * x.ndim
-                if ta and x.shape[-2] % sh.axis_size(self.mesh, ta) == 0:
-                    spec[x.ndim - 2] = ta
-                return NamedSharding(self.mesh, P(*spec))
-
-            pools = jax.device_put(
-                pools, jax.tree_util.tree_map(pool_sharding, pools))
-            if fixed:
-                fixed = jax.device_put(
-                    fixed, sh.cache_shardings(self.mesh, self.rules, fixed))
+        if mesh is not None and fixed:
+            fixed = jax.device_put(
+                fixed, sh.cache_shardings(mesh, self.rules, fixed))
         self._pools, self._fixed = pools, fixed
         self._cur = jnp.zeros((self.cfg.max_batch,), jnp.int32)
         if self.mesh is not None:
@@ -663,11 +670,14 @@ class Engine:
         leaves row-scattered to their slots, first token sampled into
         ``cur``.  The row count is fixed per engine, so this compiles once
         per plen bucket (shapes carry the key)."""
+        from repro.core import allreduce_tally
         prefill = self.model.prefill
 
         def admit_fn(params, batch, scratch, pools, fixed, cur, key,
                      dest_idx, slot_map):
-            logits0, filled = prefill(params, batch, scratch)
+            with allreduce_tally() as tally:
+                logits0, filled = prefill(params, batch, scratch)
+            self._admit_allreduce[batch["tokens"].shape[1]] = tally.bytes
             pools_out = jax.tree_util.tree_map(
                 lambda pool, src: paged_scatter(pool, dest_idx, src),
                 pools, filled["self"])
@@ -775,6 +785,7 @@ class Engine:
         and the final advance's sample becomes the next chunk's first
         token (carried device-resident in ``cur``).
         """
+        from repro.core import allreduce_tally
         decode = self.model.decode_step
         eos = self.cfg.eos_token
 
@@ -809,8 +820,11 @@ class Engine:
                         def advance(op):
                             cache, cur, key, offset = op
                             key, sub = jax.random.split(key)
-                            logits, cache = decode(params, cur[:, None],
-                                                   cache, offset, kv_start)
+                            with allreduce_tally() as tally:
+                                logits, cache = decode(params, cur[:, None],
+                                                       cache, offset,
+                                                       kv_start)
+                            self._step_allreduce[width] = tally.bytes
                             return (cache, self._sample(logits, sub), key,
                                     offset + 1)
 
@@ -824,7 +838,7 @@ class Engine:
 
             carry = (jnp.int32(0), cur, done, done.all(), buf, lens, cache,
                      jnp.int32(width - chunk), key)
-            _, cur, _, _, buf, lens, cache, _, key = jax.lax.while_loop(
+            _, cur, _, _, buf, lens, cache, offset, key = jax.lax.while_loop(
                 cond, body, carry)
             cols = jax.tree_util.tree_map(
                 lambda leaf: jax.lax.slice_in_dim(
@@ -833,7 +847,7 @@ class Engine:
             pools_out = jax.tree_util.tree_map(
                 lambda pool, c: paged_scatter(pool, sidx, c), pools, cols)
             fixed_out = {k: v for k, v in cache.items() if k != "self"}
-            return pools_out, fixed_out, cur, key, buf, lens
+            return pools_out, fixed_out, cur, key, buf, lens, offset
 
         return jax.jit(self._with_mesh(chunk_fn),
                        static_argnames=("width", "chunk", "unroll"))
@@ -955,6 +969,13 @@ class Engine:
         # One key per run, split per wave: waves draw decorrelated samples
         # while repeated runs stay deterministic for a fixed seed.
         key = jax.random.PRNGKey(self.cfg.seed)
+        if self.mesh is not None:
+            # replicated, as every program hands it on: a program first
+            # called with the fresh key would compile again for the key
+            # a previous program returns
+            from jax.sharding import NamedSharding, PartitionSpec
+            key = jax.device_put(key, NamedSharding(self.mesh,
+                                                    PartitionSpec()))
         # Pin the ambient hardware profile for the whole drain so the model
         # path's tile lookups (traced inside jit) resolve against the same
         # profile the engine reports in stats().
@@ -1229,6 +1250,10 @@ class Engine:
                  logits0) = self._admit_fn(
                     self.params, batch, scratch, self._pools, self._fixed,
                     self._cur, key, jnp.asarray(dest), jnp.asarray(slot_map))
+                allreduce = self._admit_allreduce[plen]
+                self._allreduce_bytes += allreduce
+                if tracing:
+                    span.set_metadata(allreduce_bytes=allreduce)
                 if self.cfg.profile:
                     # deliberate sync: profile mode wants the true prefill /
                     # decode wall-time split, not dispatch-pipeline overlap
@@ -1319,16 +1344,21 @@ class Engine:
             if tracing:
                 span.set_metadata(chunk_id=chunk_id, rows=len(rows),
                                   width=width)
-            (self._pools, self._fixed, self._cur, key, buf,
-             lens) = self._chunk_fn(
+            (self._pools, self._fixed, self._cur, key, buf, lens,
+             offset) = self._chunk_fn(
                 self.params, self._pools, self._fixed, self._cur, key,
                 jnp.asarray(gidx), jnp.asarray(sidx), jnp.asarray(kv_start),
                 jnp.asarray(budget), width=width, chunk=chunk, unroll=unroll)
         with annotate("serve.chunk.wait") as span:
-            if tracing:
-                span.set_metadata(chunk_id=chunk_id)
             # The ONE host transfer of this chunk.
-            buf_h, lens_h = jax.device_get((buf, lens))  # analysis: allow(TP001)
+            buf_h, lens_h, offset_h = jax.device_get((buf, lens, offset))  # analysis: allow(TP001)
+            # the loop's offset starts at width - chunk, one up per step
+            allreduce = (self._step_allreduce[width]
+                         * (int(offset_h) - (width - chunk)))
+            self._allreduce_bytes += allreduce
+            if tracing:
+                span.set_metadata(chunk_id=chunk_id,
+                                  allreduce_bytes=allreduce)
         self._stats["decode_seconds"] += time.perf_counter() - t0
         self._stats["device_transfers"] += 1
         self._stats["chunks"] += 1
@@ -1642,6 +1672,7 @@ class Engine:
                                 if self._csched is not None else 0)
             out["preemptions"] = (self._csched.preemptions
                                   if self._csched is not None else 0)
+            out["allreduce_bytes"] = self._allreduce_bytes
         out["prefix_cache"] = (self._prefix.stats()
                                if self._prefix is not None
                                else PrefixCache.disabled_stats())

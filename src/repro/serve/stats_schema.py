@@ -27,7 +27,7 @@ import dataclasses
 from typing import Dict, List
 
 #: bump on any key add/remove/rename (v1 = the implicit pre-schema dict)
-SCHEMA_VERSION = 2
+SCHEMA_VERSION = 3
 
 #: presence conditions
 ALWAYS = "always"
@@ -69,6 +69,13 @@ STATS_SCHEMA: Dict[str, StatKey] = {
                                 "device->host fetches (one per chunk/wave)"),
     "cache_allocs": StatKey("traffic", ALWAYS,
                             "KV pool/cache allocations (1 per engine)"),
+    "allreduce_bytes": StatKey("traffic", CONTINUOUS,
+                               "operand bytes of each device's explicit "
+                               "all-reduces (row-parallel GEMM f32 partial "
+                               "sums, every layer scan's trip count) over "
+                               "the admission and decode runs so far; "
+                               "reductions the compiler inserts are not "
+                               "counted; 0 single-device"),
     # -- timing ----------------------------------------------------------
     "prefill_seconds": StatKey("timing", ALWAYS,
                                "wall-clock in prefill (incl. cache restore)"),

@@ -528,3 +528,176 @@ def test_matmul_on_a_mesh_requires_w_axes(backend):
     assert y.shape == (2, 3, 8)
     assert calls == [((6, 16, 8), (6, 16, 8))]
     assert matmul(x, w).shape == (2, 3, 8)     # no mesh: no axes needed
+
+
+_YI_TP4 = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import dataclasses, json
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs.catalog import get_config
+    from repro.core import execution_context
+    from repro.models import build_model
+    from repro.serve import Engine, Request, ServeConfig
+
+    HI = jax.lax.Precision.HIGHEST
+    # yi-9b's block at toy widths: 8 query heads over 4 KV heads (one per
+    # shard on model=4), no QKV bias, rotary over the whole head, untied
+    # head; FFN 172 = 4 x 43 splits into unaligned shards, as 11008 does
+    cfg = dataclasses.replace(get_config("yi-9b").reduced(), num_heads=8,
+                              num_kv_heads=4, d_ff=172,
+                              attention_impl="flash")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(3))
+    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+
+    def rms(x, scale):
+        return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True)
+                                 + cfg.norm_eps) * scale
+
+    def rope(x):
+        half = hd // 2
+        freqs = cfg.rope_theta ** (-np.arange(half, dtype=np.float32) / half)
+        ang = np.arange(x.shape[0], dtype=np.float32)[:, None] * freqs
+        cos, sin = np.cos(ang)[:, None, :], np.sin(ang)[:, None, :]
+        x1, x2 = x[..., :half], x[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+    def reference(tokens):
+        # plain float32 forward of the whole sequence: logits (S, V)
+        p = jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32),
+                                   jax.device_get(params))
+        s = len(tokens)
+        x = p["embedding"][jnp.asarray(tokens)]
+        causal = np.tril(np.ones((s, s), bool))
+        for layer in range(cfg.num_layers):
+            b = jax.tree_util.tree_map(lambda a: a[layer], p["blocks"])
+            a = b["attn"]
+            y = rms(x, b["ln1"]["scale"])
+            q = rope(jnp.dot(y, a["wq"], precision=HI).reshape(s, h, hd))
+            k = rope(jnp.dot(y, a["wk"], precision=HI).reshape(s, kvh, hd))
+            v = jnp.dot(y, a["wv"], precision=HI).reshape(s, kvh, hd)
+            k, v = (jnp.repeat(t, h // kvh, axis=1) for t in (k, v))
+            sc = jnp.einsum("qhd,khd->hqk", q, k, precision=HI) / np.sqrt(hd)
+            pr = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+            o = jnp.einsum("hqk,khd->qhd", pr, v, precision=HI)
+            x = x + jnp.dot(o.reshape(s, h * hd), a["wo"], precision=HI)
+            y = rms(x, b["ln2"]["scale"])
+            m = b["mlp"]
+            f = (jax.nn.silu(jnp.dot(y, m["w_gate"], precision=HI))
+                 * jnp.dot(y, m["w_up"], precision=HI))
+            x = x + jnp.dot(f, m["w_down"], precision=HI)
+        return jnp.dot(rms(x, p["ln_f"]["scale"]), p["lm_head"], precision=HI)
+
+    # decode-step logits leave each chunk run through a host callback (a
+    # decode step samples max_batch rows, an admission one)
+    decode_logits = []
+    real_sample = Engine._sample
+
+    def sample(self, logits, key):
+        if logits.shape[0] == self.cfg.max_batch:
+            jax.debug.callback(
+                lambda v: decode_logits.append(np.asarray(v)), logits)
+        return real_sample(self, logits, key)
+
+    Engine._sample = sample
+    rng = np.random.default_rng(5)
+    # three rows in slots 0-2, admitted at buckets 8, 16 and 32, which
+    # finish in different chunks; the last chunk stops early
+    reqs = [(rng.integers(0, cfg.vocab_size, n).tolist(), new)
+            for n, new in ((5, 6), (13, 9), (30, 5))]
+    kw = dict(max_batch=4, max_len=64, page_size=16, decode_chunk=4)
+    out = {}
+    with execution_context(backend="pallas-interpret"):
+        for name, mesh in (("tp4", "data=1,model=4"), ("one", None)):
+            eng = Engine(model, params, ServeConfig(mesh=mesh, **kw))
+            real_admit = eng._build_admit_fn()
+            prefills = []
+
+            def admit(*args, real_admit=real_admit, prefills=prefills):
+                res = real_admit(*args)
+                prefills.append((int(args[8][0]), args[1]["tokens"].shape[1],
+                                 np.asarray(res[4][0])))
+                return res
+
+            eng._admit_fn = admit
+            decode_logits.clear()
+            hs = [eng.submit(Request(prompt=p, max_new_tokens=n))
+                  for p, n in reqs]
+            eng.run()
+            out[name] = dict(
+                tokens=[h.result(timeout=0).tokens for h in hs],
+                prefills=list(prefills), decode=list(decode_logits),
+                allreduce=eng.stats()["allreduce_bytes"])
+            # a new drain whose first admission reuses a bucket that the
+            # first drain compiled after a chunk had run
+            eng.submit(Request(prompt=rng.integers(0, cfg.vocab_size,
+                                                   12).tolist(),
+                               max_new_tokens=2))
+            eng.run()
+            out[name]["admit_compiles"] = real_admit._cache_size()
+
+    tp4 = out["tp4"]
+    errs, scale = [], 0.0
+    for slot, (prompt, _) in enumerate(reqs):
+        ref = np.asarray(reference(prompt + tp4["tokens"][slot]))
+        scale = max(scale, float(np.abs(ref).max()))
+        n = len(prompt)
+        (got,) = [lg for s, _, lg in tp4["prefills"] if s == slot]
+        errs.append(float(np.abs(got - ref[n - 1]).max()))
+        # the k-th decode step reads the row's token k and gives the
+        # logits of position n + k, for the row's served tokens after it
+        for k in range(len(tp4["tokens"][slot]) - 1):
+            errs.append(float(np.abs(tp4["decode"][k][slot] - ref[n + k])
+                              .max()))
+    d, layers, b = cfg.d_model, cfg.num_layers, kw["max_batch"]
+    analytic = (2 * layers * d * 4
+                * (sum(plen for _, plen, _ in tp4["prefills"])
+                   + b * len(tp4["decode"])))
+    print("RESULT " + json.dumps({
+        "err": max(errs), "scale": scale, "compared": len(errs),
+        "parity": out["one"]["tokens"] == tp4["tokens"],
+        "allreduce": [tp4["allreduce"], out["one"]["allreduce"]],
+        "analytic": analytic, "steps": len(tp4["decode"]),
+        "buckets": sorted(plen for _, plen, _ in tp4["prefills"]),
+        "admit_compiles": [tp4["admit_compiles"],
+                           out["one"]["admit_compiles"]]}))
+""")
+
+
+def test_yi_tensor_parallel_engine_matches_float32_reference():
+    """yi-9b's block on a data=1,model=4 mesh of four CPU devices, served
+    by the continuous engine with the Pallas kernels per shard: the
+    admission logits and every decode step's logits through the paged
+    cache agree with a plain float32 forward of the served sequence, and
+    ``allreduce_bytes`` is two f32 [rows, d_model] partial sums per layer
+    per model step (0 on one device).
+
+    Tolerance 1e-5 of the largest reference logit: the program and the
+    reference both compute in float32 and differ only in the order of sums
+    (the kernels' K blocks, four partial sums added by the all-reduce, the
+    flash kernel's online softmax), which read 8.4e-7 of that scale here.
+    Rounding the weights alone to bfloat16 moves the reference's logits by
+    9.1e-3 of it, so a program a precision below float32 fails."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _YI_TP4],
+                          capture_output=True, text=True, env=env,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][-1]
+    rec = json.loads(line[len("RESULT "):])
+    assert rec["buckets"] == [8, 16, 32], rec
+    # one admission program per bucket, however a drain starts: the run's
+    # PRNG key enters every program with the layout programs hand it on
+    assert rec["admit_compiles"] == [3, 3], rec
+    assert rec["compared"] == 3 + 5 + 8 + 4, rec
+    assert rec["err"] <= 1e-5 * rec["scale"], rec
+    assert rec["parity"], rec
+    # every admission and decode step on the mesh; none on one device
+    assert rec["allreduce"] == [rec["analytic"], 0], rec
+    # 9 tokens need 8 steps; the chunk in which the last row ends stops
+    # at its last token
+    assert rec["steps"] == 8, rec

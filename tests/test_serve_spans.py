@@ -199,8 +199,12 @@ def test_span_args_built_only_while_recording(engine, monkeypatch,
         by = {sp.name: sp.args for sp in log}
         assert set(by["serve.admit"]) == {"admit_id", "rows", "batch",
                                           "bucket", "prompt_tokens",
-                                          "cached_tokens"}
+                                          "cached_tokens", "allreduce_bytes"}
         assert set(by["serve.chunk"]) == {"chunk_id", "rows", "width"}
+        assert set(by["serve.chunk.wait"]) == {"chunk_id", "allreduce_bytes"}
+        # one device: no all-reduce in either program
+        assert by["serve.admit"]["allreduce_bytes"] == 0
+        assert by["serve.chunk.wait"]["allreduce_bytes"] == 0
         assert set(by["serve.request"]) == {"rid", "admit_id", "front_us",
                                             "queue_us", "hit"}
     else:
